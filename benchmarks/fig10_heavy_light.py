@@ -54,4 +54,6 @@ def run(sizes=(1, 4, 16, 64, 256), seed=11):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -52,4 +52,6 @@ def run(light_rate=50.0, heavy_rates=(0, 20, 80, 200, 400), duration=12.0,
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -32,4 +32,6 @@ def run(rates=(10, 40, 120, 250), duration=10.0,
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -57,4 +57,6 @@ def run(cores=(1, 2, 4, 8, 16, 32), n=64, mix="shopping", seed=23):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

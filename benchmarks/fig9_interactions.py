@@ -41,4 +41,6 @@ def run(n_per_kind=32, seed=17, kinds=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -299,6 +299,8 @@ def run(smoke: bool = False) -> Dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import json
     import sys
     print(json.dumps(run(smoke="--smoke" in sys.argv), indent=2))

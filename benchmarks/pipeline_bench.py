@@ -68,4 +68,6 @@ def run(n: int = 150, reps: int = 4, seed: int = 7):
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run(int(sys.argv[1]) if len(sys.argv) > 1 else 150)

@@ -116,10 +116,21 @@ def write_bench_pr6(smoke: bool, pr5_record: dict) -> dict:
 
 
 def write_bench_pr5(smoke: bool) -> dict:
-    """Run the sharded bench in a subprocess (it forces the 8-device
-    host platform before jax initializes) and fold the record into
-    ``BENCH_PR5.json``.  A failing subprocess fails the run — the SLA
-    gate must never see a silently missing record."""
+    """Run the sharded bench in a subprocess on 8 forced host CPU
+    devices (set for the child before its jax initializes) and fold the
+    record into ``BENCH_PR5.json``.  A failing subprocess fails the run
+    — the SLA gate must never see a silently missing record.
+
+    On an accelerator host this process already holds the chip, and a
+    child that touches JAX could not reach it; the record is refused
+    there instead of silently measuring the CPU."""
+    import jax
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            "BENCH_PR5 is a forced-host-CPU record measured in a child "
+            f"process; this process holds the {jax.default_backend()} "
+            "device, so run `python -m benchmarks.sharded_bench` on its "
+            "own instead")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     if "--xla_force_host_platform_device_count" not in \
@@ -231,6 +242,8 @@ def _existing_bench_records():
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     quick = "--quick" in sys.argv
     t_start = time.time()
 

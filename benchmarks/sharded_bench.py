@@ -26,30 +26,24 @@ exactly what row-range sharding scatters across the mesh
                  measure overhead honesty (ceilings + the delta paths
                  still engaging), not the mesh speedup.
 
-Runs in a SUBPROCESS of ``benchmarks/run.py`` with
-``--xla_force_host_platform_device_count`` set, so the PR-3/4 records
-keep measuring on the plain single-device client:
+``benchmarks/run.py`` runs it in a SUBPROCESS on forced host CPU
+devices (it sets ``JAX_PLATFORMS=cpu`` and
+``--xla_force_host_platform_device_count=8`` for the child only), so
+the PR-3/4 records keep measuring on the plain single-device client.
+Run alone it uses whatever devices JAX finds:
 
-    python -m benchmarks.sharded_bench [--smoke]   # prints JSON record
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        python -m benchmarks.sharded_bench [--smoke]   # prints JSON record
 
 ``run.py`` folds the record into ``BENCH_PR5.json``;
 ``tests/test_sla_gate.py`` gates it against stored thresholds.
 """
 from __future__ import annotations
 
-import os
+import time
+from typing import Dict
 
-if "--xla_force_host_platform_device_count" not in \
-        os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = " ".join(
-        [os.environ.get("XLA_FLAGS", ""),
-         "--xla_force_host_platform_device_count=8"]).strip()
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import time                                               # noqa: E402
-from typing import Dict                                   # noqa: E402
-
-import numpy as np                                        # noqa: E402
+import numpy as np
 
 SCALE_ITEMS = 4096
 SHARDS = 4
@@ -108,6 +102,7 @@ def engine_beats(scale_items: int = SCALE_ITEMS, shards: int = SHARDS,
     """Engine-level context on forced host devices: reseed beat walls
     (1-shard vs sharded mesh, interleaved beat-for-beat) and the
     sharded steady-state delta beat with its path fractions."""
+    import jax
     from repro.core.executor import SharedDBEngine
     from repro.core.sharding import make_row_mesh
     from repro.workloads import tpcw
@@ -174,7 +169,8 @@ def engine_beats(scale_items: int = SCALE_ITEMS, shards: int = SHARDS,
     sharded_delta_us = float(np.mean(dwalls)) * 1e6
     single_delta_us = float(np.mean(dwalls_single)) * 1e6
     return {"scale_items": scale_items, "shards": shards,
-            "beats": beats, "devices_forced": True,
+            "beats": beats,
+            "devices_forced": jax.default_backend() == "cpu",
             "single_reseed_us": float(np.mean(walls["single"])) * 1e6,
             "sharded_reseed_us": float(np.mean(walls["sharded"])) * 1e6,
             "delta_heartbeat_us": sharded_delta_us,
@@ -194,4 +190,7 @@ def run(smoke: bool = False) -> Dict:
 if __name__ == "__main__":
     import json
     import sys
+
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print(json.dumps(run(smoke="--smoke" in sys.argv), indent=2))

@@ -11,7 +11,10 @@ import numpy as np
 
 from repro.core import sla
 from repro.core.executor import SharedDBEngine
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.workloads import tpcw
+
+enable_compile_cache()
 
 rng = np.random.default_rng(0)
 SCALE = dict(scale_items=1000, scale_customers=2880)

@@ -6,6 +6,9 @@ admission, one always-on compiled plan, bounded per-cycle work.
 import sys
 
 from repro.launch import serve
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 arch = sys.argv[1] if len(sys.argv) > 1 else "recurrentgemma-2b"
 serve.main(["--arch", arch, "--smoke", "--requests", "24",

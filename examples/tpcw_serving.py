@@ -13,7 +13,10 @@ import numpy as np
 
 from repro.core.baseline import QueryAtATimeEngine
 from repro.core.executor import SharedDBEngine
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.workloads import tpcw
+
+enable_compile_cache()
 
 n = int(sys.argv[1]) if len(sys.argv) > 1 else 150
 rng = np.random.default_rng(1)

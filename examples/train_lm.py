@@ -9,6 +9,9 @@ import sys
 import tempfile
 
 from repro.launch import train
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 arch = sys.argv[1] if len(sys.argv) > 1 else "mamba2-370m"
 ckpt = tempfile.mkdtemp(prefix="repro_ckpt_")

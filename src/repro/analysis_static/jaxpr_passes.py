@@ -28,7 +28,7 @@ import re
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 import jax
-import jax.core as jcore
+import jax.extend.core as jcore
 
 from repro.analysis_static.diagnostics import LintFinding
 from repro.analysis_static import registry as R

@@ -34,17 +34,16 @@ def geometry_from_lowered(lowered, update_slots=None
     predicated scan stage, one ``JoinGeom`` per carried join (block
     joins arrive as single-bucket pseudo-partitions over the full PK
     pane)."""
-    from repro.kernels.fused_delta import PANE_TILE, JoinGeom, ScanGeom
+    from repro.kernels.fused_delta import JoinGeom, ScanGeom, pane_tiling
     cat = lowered.plan.catalog
     sgeom, jgeom = [], []
     for st in lowered.scans:
         if not st.cols:
             continue
-        T = cat.schemas[st.table].capacity
-        Rt = min(PANE_TILE, T)
+        R, nt = pane_tiling(cat.schemas[st.table].capacity)
         sgeom.append(ScanGeom(
             C=len(st.cols), Q=st.q_window, A=st.delta_words,
-            R=Rt, nt=-(-T // Rt), D=cat.schemas[st.table].dirty_cap))
+            R=R, nt=nt, D=cat.schemas[st.table].dirty_cap))
     for j in lowered.joins:
         if j.kind == "gather":
             continue
@@ -172,8 +171,9 @@ def lint_gather_bounds(sgeom, jgeom, sdesc,
 def _eval_index_map(spec, i: np.ndarray, sdesc: np.ndarray
                     ) -> Tuple[np.ndarray, ...]:
     """Evaluate a BlockSpec's index map for every grid step at once
-    (the maps are elementwise in ``i``)."""
-    got = spec.index_map(i, sdesc)
+    (the maps are elementwise in ``i`` and read the descriptor in its
+    flattened scalar-prefetch form)."""
+    got = spec.index_map(i, sdesc.reshape(-1))
     return tuple(np.asarray(g) for g in got)
 
 

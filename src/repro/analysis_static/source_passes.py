@@ -30,8 +30,12 @@ HOT_PATH_MODULES = (
     "core/folding.py",
     "core/sharding.py",
     "core/backends.py",
+    "kernels/__init__.py",
+    "kernels/clockscan.py",
+    "kernels/bitmask_join.py",
+    "kernels/partitioned_join.py",
+    "kernels/shared_groupby.py",
     "kernels/fused_delta.py",
-    "kernels/ops.py",
 )
 
 

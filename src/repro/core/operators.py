@@ -5,49 +5,19 @@ queries exactly once, carrying the packed query bitmask.  Worst-case work is
 a function of table capacity only — never of the number of queries — which
 is the bounded-computation property behind the paper's SLA guarantees.
 
-The hot loops (shared scan, shared join, shared group-by) have Pallas TPU
-kernels in repro.kernels; these jnp implementations are both the CPU
-execution path and the kernels' oracles.
+The hot loops (shared scan, block/partitioned join, group-by) resolve
+through core/backends.py (Pallas TPU kernels or their kernels/ref.py
+oracles); the operators here lower straight to XLA and are shared by
+both backends.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
-
-import jax
 import jax.numpy as jnp
 
 from repro.core import dataquery as dq
 
 INT_MIN = -2147483647
 INT_MAX = 2147483647
-
-
-# ---------------------------------------------------------------------------
-# Shared scan — ClockScan (query-data join): index the queries, not the data
-# ---------------------------------------------------------------------------
-
-
-def shared_scan(cols, lo, hi, valid):
-    """Evaluate ALL queries' conjunctive range predicates in one pass.
-
-    cols:  int32[C, T]  predicated column values
-    lo,hi: int32[C, Q]  per-query inclusive bounds (full range = no pred;
-                        queries not scanning this table use [1, 0] = fail)
-    valid: bool[T]      live rows
-    Returns packed bitmask uint32[T, Q/32].
-    """
-    from repro.kernels import ops as kops
-    return kops.clockscan(cols, lo, hi, valid)
-
-
-def shared_scan_ref(cols, lo, hi, valid):
-    C, T = cols.shape
-    ok = jnp.ones((T, lo.shape[1]), bool)
-    for c in range(C):
-        x = cols[c][:, None]
-        ok &= (x >= lo[c][None, :]) & (x <= hi[c][None, :])
-    ok &= valid[:, None]
-    return dq.pack(ok)
 
 
 # ---------------------------------------------------------------------------
@@ -74,21 +44,6 @@ def shared_join_fk(fk, left_mask, pk_index, right_mask):
     return r, combined
 
 
-def shared_join_block_ref(keys_l, mask_l, keys_r, mask_r, valid_r):
-    """Block nested-loop shared join oracle (general equality keys with
-    UNIQUE right keys).  Mirrors kernels/bitmask_join.py.
-
-    Returns (matched right row per left row (-1 none), combined mask).
-    """
-    eq = (keys_l[:, None] == keys_r[None, :]) & valid_r[None, :]
-    eqi = eq.astype(jnp.uint32)
-    # unique right keys: sum over matches == the single match
-    combined = mask_l & (eqi @ mask_r)
-    rid = (eq.astype(jnp.int32)
-           @ (jnp.arange(keys_r.shape[0], dtype=jnp.int32) + 1)) - 1
-    return rid, jnp.where((rid >= 0)[:, None], combined, jnp.uint32(0))
-
-
 # ---------------------------------------------------------------------------
 # Union compression: extract the tuples at least one query wants.
 #
@@ -113,65 +68,6 @@ def compress_union(mask, cap: int):
     cmask = jnp.where(live[:, None], mask[safe], jnp.uint32(0))
     rows = jnp.where(live, safe, -1).astype(jnp.int32)
     return rows, cmask, n_wanted
-
-
-# ---------------------------------------------------------------------------
-# Shared sort + per-query Top-N (paper Fig. 4)
-# ---------------------------------------------------------------------------
-
-
-def shared_sort(sort_key, mask, descending: bool = False):
-    """ONE sort over the union of interested tuples; bitmask rides along.
-
-    Rows wanted by nobody sort to the end.  Returns (perm, sorted_mask).
-    """
-    wanted = dq.any_query(mask)
-    key = jnp.where(wanted, sort_key, INT_MAX)
-    if descending:
-        key = jnp.where(wanted, -sort_key, INT_MAX)
-    perm = jnp.argsort(key, stable=True)
-    return perm, mask[perm]
-
-
-def shared_topn(sorted_mask, n_per_query):
-    """Phase 2 of shared Top-N: per-query rank filter (cheap, per query).
-
-    sorted_mask: uint32[T, W] in sort order; n_per_query: int32[Q].
-    Returns filtered mask keeping each query's first n bits.
-    """
-    bits = dq.unpack(sorted_mask)                    # [T, Q]
-    rank = jnp.cumsum(bits.astype(jnp.int32), axis=0)
-    keep = bits & (rank <= n_per_query[None, :])
-    return dq.pack(keep)
-
-
-# ---------------------------------------------------------------------------
-# Shared group-by — aggregation as MXU matmul
-# ---------------------------------------------------------------------------
-
-
-def shared_groupby(group_code, values, mask, n_groups: int):
-    """Phase-1 grouping + per-query aggregates for ALL queries at once.
-
-    group_code: int32[T] in [0, n_groups)  (e.g. dict-encoded column)
-    values:     int32[T] aggregation operand
-    mask:       uint32[T, W]
-    Returns (count f32[G, Q], sum f32[G, Q]).
-
-    TPU mapping: one-hot(group)^T @ unpacked-mask is a dense contraction —
-    the MXU computes "all groups x all queries" in a single pass.  See
-    kernels/shared_groupby.py for the tiled Pallas version.
-    """
-    from repro.kernels import ops as kops
-    return kops.shared_groupby(group_code, values, mask, n_groups)
-
-
-def shared_groupby_ref(group_code, values, mask, n_groups: int):
-    bits = dq.unpack(mask).astype(jnp.float32)       # [T, Q]
-    onehot = jax.nn.one_hot(group_code, n_groups, dtype=jnp.float32)
-    count = onehot.T @ bits
-    ssum = onehot.T @ (bits * values[:, None].astype(jnp.float32))
-    return count, ssum
 
 
 # ---------------------------------------------------------------------------
@@ -206,27 +102,4 @@ def route_topn(mask_in_order, n_per_query, max_results: int, rows=None):
     slot = jnp.where(live, rank.reshape(-1)[safe], max_results)
     out = jnp.full((Q, max_results), -1, jnp.int32)
     out = out.at[q_idx, slot].set(rows[k_idx], mode="drop")
-    return out
-
-
-def route_results(mask_in_order, max_results: int, perm=None):
-    """Per query: first `max_results` row ids whose bit is set, in order.
-
-    mask_in_order: uint32[T, W] (already in output order, e.g. post-sort).
-    perm: optional int32[T] mapping positions back to storage row ids.
-    Returns int32[Q, max_results] row ids (-1 padded).
-    """
-    T, W = mask_in_order.shape
-    Q = W * dq.WORD
-    bits = dq.unpack(mask_in_order)                  # [T, Q]
-    rank = jnp.cumsum(bits.astype(jnp.int32), axis=0) - 1
-    rows = jnp.arange(T, dtype=jnp.int32)
-    if perm is not None:
-        rows = perm.astype(jnp.int32)
-    out = jnp.full((Q, max_results), -1, jnp.int32)
-    q_idx = jnp.broadcast_to(jnp.arange(Q)[None, :], (T, Q))
-    slot = jnp.where(bits & (rank < max_results), rank, max_results)
-    out = out.at[q_idx.reshape(-1),
-                 slot.reshape(-1)].set(
-        jnp.broadcast_to(rows[:, None], (T, Q)).reshape(-1), mode="drop")
     return out

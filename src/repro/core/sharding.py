@@ -64,8 +64,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import dataquery as dq
 from repro.core import operators as ops
@@ -90,6 +89,17 @@ _SIDE_KEYS = ("_n", "_version", "_pk_index", "_mkey", "_mvalid")
 _STACKED_KEYS = ("_dirty_rows", "_dirty_n", "_dirty_overflow")
 
 
+def make_mesh(shape, axis_names, devices) -> Mesh:
+    """The repo's one mesh constructor: every axis is ``Auto`` — sharding
+    is stated by ``shard_map`` specs and ``NamedSharding``s, never
+    propagated through array types (the ``Explicit`` default of
+    ``jax.make_mesh`` rejects the untyped gathers of the merge and the
+    LM launcher's ``with_sharding_constraint``)."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=list(devices))
+
+
 def make_row_mesh(n_shards: int) -> Mesh:
     """A 1-D ``(n_shards,)`` mesh over the first host devices."""
     devs = jax.devices()
@@ -98,8 +108,7 @@ def make_row_mesh(n_shards: int) -> Mesh:
             f"need {n_shards} devices for a {n_shards}-shard row mesh, "
             f"have {len(devs)}; on CPU set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=8")
-    return jax.make_mesh((n_shards,), (ROW_AXIS,),
-                         devices=devs[:n_shards])
+    return make_mesh((n_shards,), (ROW_AXIS,), devs[:n_shards])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -803,8 +812,9 @@ def _build_impl(lowered: LoweredPlan, backend: OperatorBackend,
             repl_out["delta_overflow"] = delta_over_repl
         return sh_out, repl_out
 
-    smap = shard_map(body, spec.mesh, in_specs=(P(spec.axis), P()),
-                     out_specs=(P(spec.axis), P()), check_rep=False)
+    smap = jax.shard_map(body, mesh=spec.mesh,
+                         in_specs=(P(spec.axis), P()),
+                         out_specs=(P(spec.axis), P()), check_vma=False)
 
     def cycle(state, carry, rid_carry, queries, updates):
         sh_tables, sides = {}, {}

@@ -11,20 +11,31 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict
+from typing import Dict, Optional
 
 from repro.core.plan import CompiledPlan
+from repro.roofline.analysis import TARGET_DEVICE_KIND, peaks
 
 
 @dataclasses.dataclass(frozen=True)
 class HwModel:
-    flops_per_s: float = 197e12      # per chip (TPU v5e bf16)
-    bytes_per_s: float = 819e9       # HBM
+    flops_per_s: float               # per chip
+    bytes_per_s: float               # HBM
     sort_const: float = 8.0          # comparisons per element per log2
 
+    @classmethod
+    def for_device(cls, device_kind: str = TARGET_DEVICE_KIND
+                   ) -> "HwModel":
+        """The published peaks of ``device_kind`` (``roofline.PEAKS``;
+        an unknown kind raises)."""
+        hw = peaks(device_kind)
+        return cls(flops_per_s=hw["peak_flops"], bytes_per_s=hw["hbm_bw"])
 
-def cycle_cost(plan: CompiledPlan, hw: HwModel = HwModel()) -> Dict:
-    """Worst-case per-cycle flops/bytes per plan node (single chip)."""
+
+def cycle_cost(plan: CompiledPlan, hw: Optional[HwModel] = None) -> Dict:
+    """Worst-case per-cycle flops/bytes per plan node (single chip of
+    ``hw``; the target part's peaks when omitted)."""
+    hw = hw or HwModel.for_device()
     Q = plan.qcap
     W = Q // 32
     nodes = {}
@@ -68,7 +79,7 @@ def cycle_cost(plan: CompiledPlan, hw: HwModel = HwModel()) -> Dict:
 
 
 def provision(plan: CompiledPlan, sla_seconds: float,
-              hw: HwModel = HwModel()) -> Dict:
+              hw: Optional[HwModel] = None) -> Dict:
     """Chips needed so worst-case latency (2 cycles) meets the SLA,
     assuming operator replication / partitioning scales linearly (§4.5)."""
     cost = cycle_cost(plan, hw)

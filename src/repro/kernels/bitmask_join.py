@@ -1,20 +1,20 @@
-"""Shared block join kernel: key-equality outer compare fused with
-query-set intersection (the paper's shared join, §3.3).
+"""Shared block join kernel: key-equality outer compare (the paper's
+shared join, §3.3) for small index-less PK tables.
 
-  grid = (T_left // TILE_L, T_right // TILE_R)   (right tiles innermost —
-                                                  sequential reduction)
-  blocks: keys_l [TILE_L], mask_l [TILE_L, W],
-          keys_r [TILE_R], mask_r [TILE_R, W], valid_r [TILE_R]
-  outs:   rid    [TILE_L]        matched right row (-1 = none)
-          out    [TILE_L, W]     mask_l & mask_r[match]
+  grid = (Tl // TILE_L, Tr // TILE_R)   (right tiles innermost —
+                                         sequential reduction)
+  blocks: keys_l [1, TILE_L]            left keys on the lanes
+          keys_r [TILE_R, 1]            right keys on the sublanes
+          valid_r [TILE_R, 1]           int32 0/1
+  out:    rid    [1, TILE_L]            matched right row + 1 (0 = none)
 
-Inner tile computes eq = keys_l x keys_r outer equality, then accumulates
-  mask  += eq @ mask_r      (unique right keys => sum == the single match;
-                             an integer contraction — MXU-adjacent)
-  rid   = max(rid, eq * (row+1))
-The final right tile ANDs in mask_l and converts rid to -1-based.  The
-query-set intersection here IS the amended join predicate
-``R.query_id = S.query_id`` of the paper.
+Each program compares one right tile against one left tile and keeps the
+largest matching right row by a sublane max, so duplicates resolve to the
+max row id exactly as the oracle does.  With unique right keys the
+matched row alone determines the join, so the query-set intersection
+``mask_l & mask_r[rid]`` — the paper's amended ``R.query_id =
+S.query_id`` join predicate — is one O(Tl) gather after the kernel,
+shared with the partitioned join.
 """
 from __future__ import annotations
 
@@ -24,77 +24,58 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_L = 256
-TILE_R = 256
+from repro.kernels.clockscan import LANES, round_up
+
+TILE_L = 512
+TILE_R = 512
+SUBLANES = 8
 
 
-def _kernel(keys_l_ref, mask_l_ref, keys_r_ref, mask_r_ref, valid_r_ref,
-            rid_ref, out_ref, *, n_right_tiles: int, tile_r: int):
+def _kernel(keys_l_ref, keys_r_ref, valid_r_ref, rid_ref, *, tile_r: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         rid_ref[...] = jnp.zeros_like(rid_ref)
-        out_ref[...] = jnp.zeros_like(out_ref)
 
-    keys_l = keys_l_ref[...]                         # [Tl]
-    keys_r = keys_r_ref[...]                         # [Tr]
-    eq = (keys_l[:, None] == keys_r[None, :]) & valid_r_ref[...][None, :]
-    eq_u = eq.astype(jnp.uint32)
-    # sum over the (unique-key) match: [Tl, Tr] x [Tr, W] contraction
-    acc = jnp.einsum("lr,rw->lw", eq_u, mask_r_ref[...])
-    out_ref[...] = out_ref[...] | acc.astype(jnp.uint32)
-    base = j * tile_r
-    rows = base + jnp.arange(keys_r.shape[0], dtype=jnp.int32) + 1
-    cand = jnp.max(jnp.where(eq, rows[None, :], 0), axis=1)
+    eq = (keys_r_ref[...] == keys_l_ref[...]) & (valid_r_ref[...] != 0)
+    rows = (jax.lax.broadcasted_iota(jnp.int32, (eq.shape[0], 1), 0)
+            + j * tile_r + 1)                                # [Tr, 1]
+    cand = jnp.max(jnp.where(eq, rows, 0), axis=0, keepdims=True)
     rid_ref[...] = jnp.maximum(rid_ref[...], cand)
 
-    @pl.when(j == n_right_tiles - 1)
-    def _finalize():
-        matched = rid_ref[...] > 0
-        out_ref[...] = jnp.where(matched[:, None],
-                                 out_ref[...] & mask_l_ref[...],
-                                 jnp.uint32(0))
-        rid_ref[...] = rid_ref[...] - 1
+
+def intersect_matched(rid, mask_l, mask_r):
+    """mask_l & mask_r[rid] where rid matched (-1 = no match -> empty)."""
+    safe = jnp.clip(rid, 0, mask_r.shape[0] - 1)
+    return jnp.where((rid >= 0)[:, None], mask_l & mask_r[safe],
+                     jnp.uint32(0))
 
 
 def bitmask_join_pallas(keys_l, mask_l, keys_r, mask_r, valid_r, *,
-                        interpret: bool = True):
-    Tl_orig, W = mask_l.shape
-    Tr_orig = keys_r.shape[0]
-    # arbitrary table capacities: pad to tile multiples (padded right rows
-    # are invalid so they can never match; padded left rows are sliced
-    # off), matching clockscan/shared_groupby's internal padding
-    pad_l = (-Tl_orig) % min(TILE_L, max(Tl_orig, 1))
-    pad_r = (-Tr_orig) % min(TILE_R, max(Tr_orig, 1))
-    if pad_l:
-        keys_l = jnp.pad(keys_l, (0, pad_l))
-        mask_l = jnp.pad(mask_l, ((0, pad_l), (0, 0)))
-    if pad_r:
-        keys_r = jnp.pad(keys_r, (0, pad_r))
-        mask_r = jnp.pad(mask_r, ((0, pad_r), (0, 0)))
-        valid_r = jnp.pad(valid_r, (0, pad_r))
-    Tl, Tr = Tl_orig + pad_l, Tr_orig + pad_r
-    tl, tr = min(TILE_L, Tl), min(TILE_R, Tr)
-    kernel = functools.partial(_kernel, n_right_tiles=Tr // tr, tile_r=tr)
-    rid, mask = pl.pallas_call(
-        kernel,
-        grid=(Tl // tl, Tr // tr),
+                        interpret: bool):
+    """Same contract as kernels/ref.bitmask_join_ref."""
+    Tl = keys_l.shape[0]
+    Tr = keys_r.shape[0]
+    tl = min(TILE_L, round_up(Tl, LANES))
+    tr = min(TILE_R, round_up(Tr, SUBLANES))
+    Tlp, Trp = round_up(Tl, tl), round_up(Tr, tr)
+    # arbitrary table capacities: padded right rows are invalid so they
+    # never match; padded left rows are sliced off
+    kl = jnp.pad(keys_l.astype(jnp.int32), (0, Tlp - Tl))[None, :]
+    kr = jnp.pad(keys_r.astype(jnp.int32), (0, Trp - Tr))[:, None]
+    vr = jnp.pad(valid_r.astype(jnp.int32), (0, Trp - Tr))[:, None]
+    rid = pl.pallas_call(
+        functools.partial(_kernel, tile_r=tr),
+        grid=(Tlp // tl, Trp // tr),
         in_specs=[
-            pl.BlockSpec((tl,), lambda i, j: (i,)),
-            pl.BlockSpec((tl, W), lambda i, j: (i, 0)),
-            pl.BlockSpec((tr,), lambda i, j: (j,)),
-            pl.BlockSpec((tr, W), lambda i, j: (j, 0)),
-            pl.BlockSpec((tr,), lambda i, j: (j,)),
+            pl.BlockSpec((1, tl), lambda i, j: (0, i)),
+            pl.BlockSpec((tr, 1), lambda i, j: (j, 0)),
+            pl.BlockSpec((tr, 1), lambda i, j: (j, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((tl,), lambda i, j: (i,)),
-            pl.BlockSpec((tl, W), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Tl,), jnp.int32),
-            jax.ShapeDtypeStruct((Tl, W), jnp.uint32),
-        ],
+        out_specs=pl.BlockSpec((1, tl), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Tlp), jnp.int32),
         interpret=interpret,
-    )(keys_l, mask_l, keys_r, mask_r, valid_r)
-    return rid[:Tl_orig], mask[:Tl_orig]
+    )(kl, kr, vr)
+    rid = rid[0, :Tl] - 1
+    return rid, intersect_matched(rid, mask_l, mask_r)

@@ -64,8 +64,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                     ).astype(o_ref.dtype)
 
 
-def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
-                           interpret: bool = True,
+def flash_attention_pallas(q, k, v, *, interpret: bool, causal: bool = True,
+                           window: int = 0,
                            block_q: int = BLOCK_Q, block_k: int = BLOCK_K):
     """q: [B, Sq, H, D]; k, v: [B, Sk, KV, D] -> [B, Sq, H, D]."""
     B, Sq, H, D = q.shape

@@ -11,28 +11,36 @@ that dispatch chain, not by compute.  This kernel collapses the chain:
   grid = (N,)   N = Σ_stages (pane tiles + dirty slots) + Σ_joins slots
 
 one flat grid whose every program is ONE unit of delta work, routed by a
-scalar-prefetched work descriptor ``sdesc int32[N, 4]``:
+scalar-prefetched work descriptor ``sdesc int32[N, 4]`` (flattened to
+``int32[4N]`` in SMEM):
 
   sdesc[i] = (kind, owner, idx, gather)
 
-  kind 0 (PANE)  — one ``PANE_TILE``-row tile of stage ``owner``'s
-                   admission-pane compare: the pane-width predicate
-                   slices (lo_p/hi_p, pre-sliced at w0 by the caller)
-                   against the tile's column values, bit-packed to
-                   ``A`` words per row.  ``idx`` picks the tile.
+  kind 0 (PANE)  — one ``R``-row tile of stage ``owner``'s admission-
+                   pane compare: the pane-width predicate slices
+                   (lo_p/hi_p, pre-sliced at w0 by the caller) against
+                   the tile's column values, bit-packed to ``A`` words
+                   per row.  ``idx`` picks the tile.
   kind 1 (DIRTY) — one dirty row of stage ``owner``, re-evaluated
                    against the FULL window: ``gather`` holds the row id
-                   (pad slots clamp in range) and the BlockSpec
-                   index_map reads it to DMA exactly that column of
-                   cols — the scalar-prefetch gather.
+                   (pad slots clamp in range); the BlockSpec index_map
+                   reads it to DMA the 128-lane block of cols holding
+                   that row — the scalar-prefetch gather — and the
+                   program selects the row's lane.
   kind 2 (PROBE) — one dirty spine row of carried join ``owner``:
                    ``gather`` holds the row's bucket index (the
                    ``searchsorted`` routing runs in the XLA prologue —
-                   it needs the key VALUE, which no index_map can see)
-                   and the kernel probes that ONE bucket pane.
-                   Block-kind joins arrive as single-bucket
-                   pseudo-partitions, so every carried join probes
-                   through this same path.
+                   it needs the key VALUE, which no index_map can see);
+                   the index_map DMAs the 8-bucket block holding it, the
+                   program probes that ONE bucket pane against the row's
+                   key, read from SMEM.  Block-kind joins arrive as
+                   single-bucket pseudo-partitions, so every carried
+                   join probes through this same path.
+
+Layout on the chip: table rows sit on the 128 lanes and queries on the
+sublanes (predicate bounds arrive as ``[C, Q, 1]`` columns), validity is
+int32, every block is full-extent or (8, 128)-aligned, and outputs are
+rank-3 with the owned unit on the leading axis.
 
 Non-owning programs park on per-output GARBAGE blocks (one spare tile /
 slot appended past the real extent), so each real output block has
@@ -45,9 +53,8 @@ carries directly: the ``[Tl, B]`` candidate panes and full-window
 compare matrices of the chained path are never materialized.
 
 The standalone ``delta_scan_pallas`` / ``delta_join_pallas`` kernels
-(formerly kernels/delta_scan.py / delta_join.py) are absorbed below:
-they are the DIRTY / PROBE program bodies as free-standing calls, kept
-as the chained fallback surface (``OperatorBackend.scan_delta`` /
+are the DIRTY / PROBE program bodies as free-standing calls, kept as the
+chained fallback surface (``OperatorBackend.scan_delta`` /
 ``join_delta``) for backends or beats the fused path does not cover.
 """
 from __future__ import annotations
@@ -62,10 +69,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.storage import scatter_dirty_rows
+from repro.kernels.bitmask_join import SUBLANES
+from repro.kernels.clockscan import (LANES, as_query_column, match_ranges,
+                                     pack_words, round_up, words_to_rows)
 
-PANE_TILE = 256
+PANE_TILE = 1024
+DIRTY_LANES = LANES        # lane block a dirty row is gathered from
 
 _PANE, _DIRTY, _PROBE = 0, 1, 2
+_NCOL = 4                  # sdesc columns: (kind, owner, idx, gather)
 
 
 class ScanGeom(NamedTuple):
@@ -73,7 +85,7 @@ class ScanGeom(NamedTuple):
     C: int        # predicated columns
     Q: int        # full window width (slots)
     A: int        # admission-pane words
-    R: int        # pane tile rows (min(PANE_TILE, T))
+    R: int        # pane tile rows (a multiple of 128)
     nt: int       # pane tiles (ceil(T / R)); tile nt is the garbage tile
     D: int        # dirty-row slots; slot D is the garbage slot
 
@@ -85,12 +97,23 @@ class JoinGeom(NamedTuple):
     P: int        # bucket count (1 for block pseudo-partitions)
 
 
+def pane_tiling(T: int):
+    """(R, nt): pane tile rows and tile count for a table of T rows."""
+    R = min(PANE_TILE, round_up(T, LANES))
+    return R, -(-T // R)
+
+
+def probe_rows(P: int) -> int:
+    """Buckets per PROBE block: one sublane tile, or all of a small P."""
+    return min(SUBLANES, P)
+
+
 def scan_geometry(e) -> ScanGeom:
     """Geometry from a ``FusedScanIn``'s static shapes."""
     C, T = e.cols.shape
-    R = min(PANE_TILE, T)
+    R, nt = pane_tiling(T)
     return ScanGeom(C=C, Q=e.lo.shape[1], A=e.lo_p.shape[1] // 32,
-                    R=R, nt=-(-T // R), D=e.rows.shape[0])
+                    R=R, nt=nt, D=e.rows.shape[0])
 
 
 def join_geometry(e) -> JoinGeom:
@@ -118,8 +141,8 @@ def build_sdesc(schedule, sgeom, jgeom, scan_rows, probe_buckets):
     """Assemble the full scalar-prefetch descriptor int32[N, 4] =
     (kind, owner, idx, gather) by appending the runtime gather column:
     clamped dirty-row ids for DIRTY rows (the BlockSpec index_map DMAs
-    exactly that column), routed bucket indices for PROBE rows, zeros
-    for PANE rows (unused)."""
+    the lane block holding that row), routed bucket indices for PROBE
+    rows, zeros for PANE rows (unused)."""
     gathers = []
     for g, rows in zip(sgeom, scan_rows):
         gathers.append(jnp.zeros((g.nt,), jnp.int32))
@@ -132,9 +155,14 @@ def build_sdesc(schedule, sgeom, jgeom, scan_rows, probe_buckets):
                            axis=1)
 
 
+def _desc(d, i, k):
+    """Field ``k`` of descriptor row ``i`` in the flattened descriptor."""
+    return d[_NCOL * i + k]
+
+
 def _own(d, i, k, o):
     """Does grid step ``i``'s descriptor row target (kind k, owner o)?"""
-    return (d[i, 0] == k) & (d[i, 1] == o)
+    return (_desc(d, i, 0) == k) & (_desc(d, i, 1) == o)
 
 
 def make_in_specs(sgeom, jgeom):
@@ -145,29 +173,37 @@ def make_in_specs(sgeom, jgeom):
     specs = []
     for s, g in enumerate(sgeom):
         C, Q, A, R = g.C, g.Q, g.A, g.R
+
+        def tile(i, d, s=s):
+            return jnp.where(_own(d, i, _PANE, s), _desc(d, i, 2), 0)
+
+        def lanes(i, d, s=s):
+            return jnp.where(_own(d, i, _DIRTY, s),
+                             _desc(d, i, 3) // DIRTY_LANES, 0)
+
         specs += [
-            pl.BlockSpec((C, R), lambda i, d, s=s: (
-                0, jnp.where(_own(d, i, _PANE, s), d[i, 2], 0))),
-            pl.BlockSpec((C, 1), lambda i, d, s=s: (
-                0, jnp.where(_own(d, i, _DIRTY, s), d[i, 3], 0))),
-            pl.BlockSpec((R,), lambda i, d, s=s: (
-                jnp.where(_own(d, i, _PANE, s), d[i, 2], 0),)),
-            pl.BlockSpec((1,), lambda i, d, s=s: (
-                jnp.where(_own(d, i, _DIRTY, s), d[i, 3], 0),)),
-            pl.BlockSpec((C, Q), lambda i, d: (0, 0)),
-            pl.BlockSpec((C, Q), lambda i, d: (0, 0)),
-            pl.BlockSpec((C, 32 * A), lambda i, d: (0, 0)),
-            pl.BlockSpec((C, 32 * A), lambda i, d: (0, 0)),
+            pl.BlockSpec((C, R), lambda i, d, f=tile: (0, f(i, d))),
+            pl.BlockSpec((C, DIRTY_LANES),
+                         lambda i, d, f=lanes: (0, f(i, d))),
+            pl.BlockSpec((1, R), lambda i, d, f=tile: (0, f(i, d))),
+            pl.BlockSpec((1, DIRTY_LANES),
+                         lambda i, d, f=lanes: (0, f(i, d))),
+            pl.BlockSpec((C, Q, 1), lambda i, d: (0, 0, 0)),
+            pl.BlockSpec((C, Q, 1), lambda i, d: (0, 0, 0)),
+            pl.BlockSpec((C, 32 * A, 1), lambda i, d: (0, 0, 0)),
+            pl.BlockSpec((C, 32 * A, 1), lambda i, d: (0, 0, 0)),
         ]
     for j, g in enumerate(jgeom):
-        B = g.B
+        PB = probe_rows(g.P)
+
+        def bucket(i, d, j=j, PB=PB):
+            return jnp.where(_own(d, i, _PROBE, j), _desc(d, i, 3) // PB,
+                             0)
+
         specs += [
-            pl.BlockSpec((1,), lambda i, d, j=j: (
-                jnp.where(_own(d, i, _PROBE, j), d[i, 2], 0),)),
-            pl.BlockSpec((1, B), lambda i, d, j=j: (
-                jnp.where(_own(d, i, _PROBE, j), d[i, 3], 0), 0)),
-            pl.BlockSpec((1, B), lambda i, d, j=j: (
-                jnp.where(_own(d, i, _PROBE, j), d[i, 3], 0), 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((PB, g.B), lambda i, d, f=bucket: (f(i, d), 0)),
+            pl.BlockSpec((PB, g.B), lambda i, d, f=bucket: (f(i, d), 0)),
         ]
     return specs
 
@@ -176,35 +212,70 @@ def make_out_specs(sgeom, jgeom):
     """Output BlockSpecs + shapes: one spare (garbage) tile / slot past
     the real extent parks every non-owning program's write window, so
     each real output block has exactly one writer and no cross-program
-    masking is needed.  ``kernel_passes.lint_garbage_park`` re-evaluates
-    these maps against a concrete descriptor to prove it."""
+    masking is needed.  The owned unit is every output's LEADING block
+    index; ``kernel_passes.lint_garbage_park`` re-evaluates these maps
+    against a concrete descriptor to prove it."""
     specs, shapes = [], []
     for s, g in enumerate(sgeom):
-        specs.append(pl.BlockSpec((g.R, g.A), lambda i, d, s=s,
+        specs.append(pl.BlockSpec((1, g.A, g.R), lambda i, d, s=s,
                                   nt=g.nt: (
-            jnp.where(_own(d, i, _PANE, s), d[i, 2], nt), 0)))
+            jnp.where(_own(d, i, _PANE, s), _desc(d, i, 2), nt), 0, 0)))
         shapes.append(
-            jax.ShapeDtypeStruct(((g.nt + 1) * g.R, g.A), jnp.uint32))
-        specs.append(pl.BlockSpec((1, g.Q // 32), lambda i, d, s=s,
+            jax.ShapeDtypeStruct((g.nt + 1, g.A, g.R), jnp.int32))
+        specs.append(pl.BlockSpec((1, g.Q // 32, 1), lambda i, d, s=s,
                                   D=g.D: (
-            jnp.where(_own(d, i, _DIRTY, s), d[i, 2], D), 0)))
+            jnp.where(_own(d, i, _DIRTY, s), _desc(d, i, 2), D), 0, 0)))
         shapes.append(
-            jax.ShapeDtypeStruct((g.D + 1, g.Q // 32), jnp.uint32))
+            jax.ShapeDtypeStruct((g.D + 1, g.Q // 32, 1), jnp.int32))
     for j, g in enumerate(jgeom):
-        specs.append(pl.BlockSpec((1,), lambda i, d, j=j, D=g.D: (
-            jnp.where(_own(d, i, _PROBE, j), d[i, 2], D),)))
-        shapes.append(jax.ShapeDtypeStruct((g.D + 1,), jnp.int32))
+        specs.append(pl.BlockSpec((1, 1, 1), lambda i, d, j=j, D=g.D: (
+            jnp.where(_own(d, i, _PROBE, j), _desc(d, i, 2), D), 0, 0)))
+        shapes.append(jax.ShapeDtypeStruct((g.D + 1, 1, 1), jnp.int32))
     return specs, shapes
 
 
-def _pack_bits(ok):
-    """bool[R, 32*w] -> uint32[R, w] (32 query lanes per word)."""
-    R = ok.shape[0]
-    w = ok.shape[1] // 32
-    bits = ok.reshape(R, w, 32).astype(jnp.uint32)
-    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(bits * weights[None, None, :], axis=-1,
-                   dtype=jnp.uint32)
+# ---------------------------------------------------------------------------
+# Program bodies (shared by the mega-kernel and the standalone kernels)
+# ---------------------------------------------------------------------------
+
+
+def _row_words(cols_ref, valid_ref, lane, lo_ref, hi_ref, n_cols: int,
+               qcap: int):
+    """One gathered row against the full window -> int32[Q/32, 1].
+
+    ``cols_ref`` [C, L] / ``valid_ref`` [1, L] hold the row at lane
+    ``lane``; a masked lane sum extracts it."""
+    sel = jax.lax.broadcasted_iota(jnp.int32, valid_ref.shape, 1) == lane
+    v = jnp.sum(jnp.where(sel, valid_ref[...], 0), axis=1, keepdims=True)
+    xs = [jnp.sum(jnp.where(sel, cols_ref[c:c + 1, :], 0), axis=1,
+                  keepdims=True) for c in range(n_cols)]
+    ok = jnp.broadcast_to(v != 0, (qcap, 1))
+    return pack_words(match_ranges(ok, xs, lo_ref, hi_ref))
+
+
+def _probe_rid(bkeys_ref, brows_ref, r, key):
+    """Max valid row with ``key`` in bucket ``r`` of the [PB, B] block
+    (-1 = none) -> int32[1, 1]."""
+    rows = brows_ref[...]
+    sub = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) == r
+    hit = sub & (bkeys_ref[...] == key) & (rows >= 0)
+    m = jnp.max(jnp.where(hit, rows, -1), axis=1, keepdims=True)
+    return jnp.max(m, axis=0, keepdims=True)
+
+
+def _pad_buckets(bkeys, brows, P: int):
+    """Pad the bucket arrays to whole PROBE blocks (pad rows never hit)."""
+    pad = round_up(P, probe_rows(P)) - P
+    return (jnp.pad(bkeys, ((0, pad), (0, 0))),
+            jnp.pad(brows, ((0, pad), (0, 0)), constant_values=-1))
+
+
+def _route(keys_l, rows, bounds, P: int):
+    """XLA prologue of a dirty probe: the dirty rows' keys and the ONE
+    bucket each routes to (shared with the reference probe)."""
+    kd = keys_l[jnp.clip(rows, 0, keys_l.shape[0] - 1)].astype(jnp.int32)
+    b = jnp.searchsorted(bounds, kd, side="right").astype(jnp.int32) - 1
+    return kd, jnp.clip(b, 0, P - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -214,10 +285,12 @@ def _pack_bits(ok):
 
 def _mega_kernel(sdesc_ref, *refs, sgeom, jgeom):
     i = pl.program_id(0)
-    kind = sdesc_ref[i, 0]
-    owner = sdesc_ref[i, 1]
+    kind = _desc(sdesc_ref, i, 0)
+    owner = _desc(sdesc_ref, i, 1)
+    idx = _desc(sdesc_ref, i, 2)
+    gather = _desc(sdesc_ref, i, 3)
     n_in = 8 * len(sgeom) + 3 * len(jgeom)
-    for s, (C, Q, A, R, _nt, _D) in enumerate(sgeom):
+    for s, g in enumerate(sgeom):
         (cols_t, cols_r, valid_t, valid_r, lo, hi, lo_p,
          hi_p) = refs[8 * s:8 * s + 8]
         pane_out = refs[n_in + 2 * s]
@@ -225,36 +298,30 @@ def _mega_kernel(sdesc_ref, *refs, sgeom, jgeom):
 
         @pl.when((kind == _PANE) & (owner == s))
         def _():
-            ok = jnp.ones((R, 32 * A), jnp.bool_)
-            for c in range(C):
-                x = cols_t[c, :][:, None]                   # [R, 1]
-                ok &= (x >= lo_p[c, :][None, :]) \
-                    & (x <= hi_p[c, :][None, :])
-            ok &= valid_t[...][:, None]
-            pane_out[...] = _pack_bits(ok)
+            ok = jnp.broadcast_to(valid_t[...] != 0, (32 * g.A, g.R))
+            xs = [cols_t[c:c + 1, :] for c in range(g.C)]
+            pane_out[...] = pack_words(
+                match_ranges(ok, xs, lo_p, hi_p))[None]
 
         @pl.when((kind == _DIRTY) & (owner == s))
         def _():
-            ok = jnp.ones((1, Q), jnp.bool_)
-            for c in range(C):
-                x = cols_r[c, 0]
-                ok &= (x >= lo[c, :][None, :]) \
-                    & (x <= hi[c, :][None, :])
-            ok &= valid_r[0]
-            dwords_out[...] = _pack_bits(ok)
+            dwords_out[...] = _row_words(
+                cols_r, valid_r, gather % DIRTY_LANES, lo, hi, g.C,
+                g.Q)[None]
 
-    for j, (_B, _Dj, _P) in enumerate(jgeom):
+    for j, g in enumerate(jgeom):
         kd, bkeys, brows = refs[8 * len(sgeom) + 3 * j:
                                 8 * len(sgeom) + 3 * j + 3]
         rid_out = refs[n_in + 2 * len(sgeom) + j]
 
         @pl.when((kind == _PROBE) & (owner == j))
         def _():
-            hit = (bkeys[...] == kd[0]) & (brows[...] >= 0)  # [1, B]
-            rid_out[0] = jnp.max(jnp.where(hit, brows[...], -1))
+            rid_out[...] = _probe_rid(bkeys, brows,
+                                      gather % probe_rows(g.P),
+                                      kd[idx])[None]
 
 
-def fused_delta_pallas(scan_in, join_in, *, interpret: bool = True):
+def fused_delta_pallas(scan_in, join_in, *, interpret: bool):
     """Same contract as kernels/ref.fused_delta_ref: tuples of
     backends.FusedScanIn / FusedJoinIn -> (merged words, merged rids)."""
     scan_in, join_in = tuple(scan_in), tuple(join_in)
@@ -263,92 +330,81 @@ def fused_delta_pallas(scan_in, join_in, *, interpret: bool = True):
 
     # ---- static geometry + padded inputs -------------------------------
     sgeom = [scan_geometry(e) for e in scan_in]
-    padded = []
+    jgeom = [join_geometry(e) for e in join_in]
+    inputs = []
     for g, e in zip(sgeom, scan_in):
         pad = g.nt * g.R - e.cols.shape[1]
-        cols_p = jnp.pad(e.cols, ((0, 0), (0, pad))) if pad else e.cols
-        valid_p = jnp.pad(e.valid, (0, pad)) if pad else e.valid
-        padded.append((cols_p, valid_p))
-    jgeom = [join_geometry(e) for e in join_in]
-    probes = []
+        cols_p = jnp.pad(e.cols, ((0, 0), (0, pad)))
+        valid_p = jnp.pad(e.valid.astype(jnp.int32), (0, pad))[None, :]
+        inputs += [cols_p, cols_p, valid_p, valid_p,
+                   as_query_column(e.lo), as_query_column(e.hi),
+                   as_query_column(e.lo_p), as_query_column(e.hi_p)]
+    buckets = []
     for g, e in zip(jgeom, join_in):
-        # XLA prologue (shared with the reference probe): gather the
-        # dirty rows' keys and route each to its ONE candidate bucket
-        safe = jnp.clip(e.rows, 0, e.keys.shape[0] - 1)
-        kd = e.keys[safe]
-        b = jnp.searchsorted(e.bounds, kd,
-                             side="right").astype(jnp.int32) - 1
-        probes.append((kd, jnp.clip(b, 0, g.P - 1)))
+        kd, b = _route(e.keys, e.rows, e.bounds, g.P)
+        buckets.append(b)
+        inputs += [kd, *_pad_buckets(e.bkeys, e.brows, g.P)]
 
     # ---- the flat work descriptor (kind, owner, idx, gather) ----------
     schedule = build_schedule(sgeom, jgeom)
     sdesc = build_sdesc(schedule, sgeom, jgeom,
-                        [e.rows for e in scan_in],
-                        [b for _, b in probes])
+                        [e.rows for e in scan_in], buckets)
     N = int(schedule.shape[0])
 
     # ---- block specs: owners address real blocks, others park ---------
-    inputs = []
-    for (cols_p, valid_p), e in zip(padded, scan_in):
-        inputs += [cols_p, cols_p, valid_p, valid_p, e.lo, e.hi, e.lo_p,
-                   e.hi_p]
-    for (kd, b), e in zip(probes, join_in):
-        inputs += [kd, e.bkeys, e.brows]
-    in_specs = make_in_specs(sgeom, jgeom)
     out_specs, out_shapes = make_out_specs(sgeom, jgeom)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(N,), in_specs=in_specs,
-        out_specs=out_specs)
+        num_scalar_prefetch=1, grid=(N,),
+        in_specs=make_in_specs(sgeom, jgeom), out_specs=out_specs)
     outs = pl.pallas_call(
         functools.partial(_mega_kernel, sgeom=tuple(sgeom),
                           jgeom=tuple(jgeom)),
         grid_spec=grid_spec, out_shape=out_shapes,
-        interpret=interpret)(sdesc, *inputs)
+        interpret=interpret)(sdesc.reshape(-1), *inputs)
 
     # ---- XLA epilogue: merge into the carries (no intermediates leave
     # the op; sentinel rows drop in the bounds-checked scatter) ---------
     words = []
-    for s, ((C, Q, A, R, nt, D), e) in enumerate(zip(sgeom, scan_in)):
+    for s, (g, e) in enumerate(zip(sgeom, scan_in)):
         T = e.cols.shape[1]
-        pane = outs[2 * s][:T]                            # [T, A]
+        pane = jnp.transpose(outs[2 * s], (0, 2, 1))
+        pane = jax.lax.bitcast_convert_type(
+            pane.reshape((g.nt + 1) * g.R, g.A)[:T], jnp.uint32)
         m = jnp.where(e.span > 0,
                       jax.lax.dynamic_update_slice(e.carry, pane,
                                                    (0, e.w0)),
                       e.carry)
-        words.append(scatter_dirty_rows(m, e.rows, outs[2 * s + 1][:D],
-                                        T))
+        dwords = jax.lax.bitcast_convert_type(
+            outs[2 * s + 1][:g.D, :, 0], jnp.uint32)
+        words.append(scatter_dirty_rows(m, e.rows, dwords, T))
     rids = []
-    for j, ((B, D, _P), e) in enumerate(zip(jgeom, join_in)):
-        rid_d = outs[2 * len(sgeom) + j][:D]
+    for j, (g, e) in enumerate(zip(jgeom, join_in)):
+        rid_d = outs[2 * len(sgeom) + j][:g.D, 0, 0]
         rids.append(scatter_dirty_rows(e.rid_carry, e.rows, rid_d,
                                        e.keys.shape[0]))
     return tuple(words), tuple(rids)
 
 
 # ---------------------------------------------------------------------------
-# Absorbed standalone kernels (the chained-fallback surface)
+# Standalone kernels (the chained-fallback surface)
 # ---------------------------------------------------------------------------
 
 
 def _delta_scan_kernel(rows_ref, cols_ref, lo_ref, hi_ref, valid_ref,
                        out_ref, *, n_cols: int, qcap: int):
-    ok = jnp.ones((1, qcap), jnp.bool_)
-    for c in range(n_cols):
-        x = cols_ref[c, 0]
-        ok &= (x >= lo_ref[c, :][None, :]) & (x <= hi_ref[c, :][None, :])
-    ok &= valid_ref[0]
-    out_ref[...] = _pack_bits(ok)
+    lane = rows_ref[pl.program_id(0)] % DIRTY_LANES
+    out_ref[...] = _row_words(cols_ref, valid_ref, lane, lo_ref, hi_ref,
+                              n_cols, qcap)[None]
 
 
-def delta_scan_pallas(cols, lo, hi, valid, rows, *, interpret: bool = True):
+def delta_scan_pallas(cols, lo, hi, valid, rows, *, interpret: bool):
     """Dirty-row delta scan (contract: kernels/ref.delta_scan_ref).
 
     grid = (D,), one program per dirty-row slot; the BlockSpec index_map
-    reads the scalar-prefetched row id to DMA exactly that column of
-    cols.  Work is O(D * C * Q) — independent of the table size.  This
-    is the fused kernel's DIRTY program as a standalone call (the
-    chained ``OperatorBackend.scan_delta`` fallback).
+    reads the scalar-prefetched row id to DMA the lane block of cols
+    holding that row.  Work is O(D * C * Q) — independent of the table
+    size.  This is the fused kernel's DIRTY program as a standalone call
+    (the chained ``OperatorBackend.scan_delta`` fallback).
     """
     C, T = cols.shape
     Q = lo.shape[1]
@@ -357,70 +413,80 @@ def delta_scan_pallas(cols, lo, hi, valid, rows, *, interpret: bool = True):
         raise ValueError(
             f"delta scan window width {Q} is not a multiple of 32")
     W = Q // 32
-    kernel = functools.partial(_delta_scan_kernel, n_cols=C, qcap=Q)
+    Tp = round_up(T, DIRTY_LANES)
+    cols = jnp.pad(cols, ((0, 0), (0, Tp - T)))
+    valid = jnp.pad(valid.astype(jnp.int32), (0, Tp - T))[None, :]
+    rows = jnp.clip(rows, 0, T - 1).astype(jnp.int32)  # pad slots clamp
 
-    def row(i, rows_ref):                    # pad slots clamp in range
-        return jnp.clip(rows_ref[i], 0, T - 1)
+    def lanes(i, rows_ref):
+        return (0, rows_ref[i] // DIRTY_LANES)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(D,),
         in_specs=[
-            # the scalar-prefetch gather: rows[i] picks the cols column
-            pl.BlockSpec((C, 1), lambda i, rows_ref: (0, row(i, rows_ref))),
-            pl.BlockSpec((C, Q), lambda i, rows_ref: (0, 0)),
-            pl.BlockSpec((C, Q), lambda i, rows_ref: (0, 0)),
-            pl.BlockSpec((1,), lambda i, rows_ref: (row(i, rows_ref),)),
+            # the scalar-prefetch gather: rows[i] picks the cols block
+            pl.BlockSpec((C, DIRTY_LANES), lanes),
+            pl.BlockSpec((C, Q, 1), lambda i, rows_ref: (0, 0, 0)),
+            pl.BlockSpec((C, Q, 1), lambda i, rows_ref: (0, 0, 0)),
+            pl.BlockSpec((1, DIRTY_LANES), lanes),
         ],
-        out_specs=pl.BlockSpec((1, W), lambda i, rows_ref: (i, 0)),
+        out_specs=pl.BlockSpec((1, W, 1), lambda i, rows_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        functools.partial(_delta_scan_kernel, n_cols=C, qcap=Q),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((D, W), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((D, W, 1), jnp.int32),
         interpret=interpret,
-    )(rows.astype(jnp.int32), cols, lo, hi, valid)
+    )(rows, cols, as_query_column(lo), as_query_column(hi), valid)
+    return jax.lax.bitcast_convert_type(out[:, :, 0], jnp.uint32)
 
 
-def _delta_join_kernel(bidx_ref, kd_ref, bkeys_ref, brows_ref, rid_ref):
-    hit = (bkeys_ref[...] == kd_ref[0]) & (brows_ref[...] >= 0)  # [1, B]
-    rid_ref[0] = jnp.max(jnp.where(hit, brows_ref[...], -1))
+def _delta_join_kernel(bidx_ref, kd_ref, bkeys_ref, brows_ref, rid_ref, *,
+                       n_rows: int):
+    i = pl.program_id(0)
+    rid_ref[...] = _probe_rid(bkeys_ref, brows_ref, bidx_ref[i] % n_rows,
+                              kd_ref[i])[None]
 
 
 def delta_join_pallas(keys_l, rows, bucket_keys, bucket_rows, bounds, *,
-                      interpret: bool = True):
+                      interpret: bool):
     """Dirty-spine-row partitioned probe (contract:
     kernels/ref.delta_join_ref).
 
     grid = (D,), one program per dirty-row slot; the ``searchsorted``
     bucket routing runs in XLA outside (it needs the key VALUE, which no
-    BlockSpec index_map can see) and the kernel probes the ONE routed
-    bucket pane.  Work is O(D * B) — independent of the spine size.
-    This is the fused kernel's PROBE program as a standalone call (the
-    chained ``OperatorBackend.join_delta`` fallback).
+    BlockSpec index_map can see), the index_map DMAs the block holding
+    the routed bucket and the kernel probes that ONE bucket pane against
+    the row's key, read from SMEM.  Work is O(D * B) — independent of
+    the spine size.  This is the fused kernel's PROBE program as a
+    standalone call (the chained ``OperatorBackend.join_delta``
+    fallback).
     """
     P, B = bucket_keys.shape
-    T = keys_l.shape[0]
     D = rows.shape[0]
-    safe = jnp.clip(rows, 0, T - 1)
-    kd = keys_l[safe]
-    b = jnp.searchsorted(bounds, kd, side="right").astype(jnp.int32) - 1
-    b = jnp.clip(b, 0, P - 1)
+    PB = probe_rows(P)
+    kd, b = _route(keys_l, rows, bounds, P)
+    bkeys, brows = _pad_buckets(bucket_keys, bucket_rows, P)
+
+    def bucket(i, bidx_ref):
+        return (bidx_ref[i] // PB, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(D,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, bidx_ref: (i,)),
-            # the scalar-prefetch gather: bidx[i] picks the bucket pane
-            pl.BlockSpec((1, B), lambda i, bidx_ref: (bidx_ref[i], 0)),
-            pl.BlockSpec((1, B), lambda i, bidx_ref: (bidx_ref[i], 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            # the scalar-prefetch gather: bidx[i] picks the bucket block
+            pl.BlockSpec((PB, B), bucket),
+            pl.BlockSpec((PB, B), bucket),
         ],
-        out_specs=pl.BlockSpec((1,), lambda i, bidx_ref: (i,)),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i, bidx_ref: (i, 0, 0)),
     )
-    return pl.pallas_call(
-        _delta_join_kernel,
+    rid = pl.pallas_call(
+        functools.partial(_delta_join_kernel, n_rows=PB),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((D,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((D, 1, 1), jnp.int32),
         interpret=interpret,
-    )(b, kd, bucket_keys, bucket_rows)
+    )(b, kd, bkeys, brows)
+    return rid[:, 0, 0]

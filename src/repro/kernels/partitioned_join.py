@@ -10,13 +10,13 @@ for any key distribution.  The probe has two parts:
 
   1. bucket routing + gather (XLA): each left key finds its ONE candidate
      bucket via searchsorted over the P bucket bounds, and that bucket's
-     keys/rows are gathered to [Tl, B] candidate panes — TPU-native
-     dynamic slicing, shared verbatim with the jnp reference path.
+     keys/rows are gathered to [B, Tl] candidate panes (left rows on the
+     lanes) — TPU-native dynamic slicing.
   2. the match reduction (THIS kernel): grid over (left-tile, bucket
      chunk); each program compares a left tile against one chunk of its
-     rows' candidate panes and accumulates the matched right row id by
-     max — identical accumulation to bitmask_join's right-tile loop, but
-     over B candidates per row instead of Tr.
+     rows' candidate panes and accumulates the matched right row id by a
+     sublane max — identical accumulation to bitmask_join's right-tile
+     loop, but over B candidates per row instead of Tr.
 
 The bitmask intersection (mask_l & mask_r[rid] — the paper's amended
 ``R.query_id = S.query_id`` join predicate) is a single O(Tl) gather once
@@ -28,8 +28,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_L = 256
-TILE_B = 256
+from repro.kernels.bitmask_join import SUBLANES, intersect_matched
+from repro.kernels.clockscan import LANES, round_up
+
+TILE_L = 512
+TILE_B = 512
 
 
 def _kernel(keys_l_ref, cand_keys_ref, cand_rows_ref, rid_ref):
@@ -39,52 +42,43 @@ def _kernel(keys_l_ref, cand_keys_ref, cand_rows_ref, rid_ref):
     def _init():
         rid_ref[...] = jnp.full_like(rid_ref, -1)
 
-    keys_l = keys_l_ref[...]                          # [Tl]
-    hit = (cand_keys_ref[...] == keys_l[:, None]) \
-        & (cand_rows_ref[...] >= 0)
-    cand = jnp.max(jnp.where(hit, cand_rows_ref[...], -1), axis=1)
+    rows = cand_rows_ref[...]                                # [Bt, Tl]
+    hit = (cand_keys_ref[...] == keys_l_ref[...]) & (rows >= 0)
+    cand = jnp.max(jnp.where(hit, rows, -1), axis=0, keepdims=True)
     rid_ref[...] = jnp.maximum(rid_ref[...], cand)
 
 
 def partitioned_join_pallas(keys_l, mask_l, bucket_keys, bucket_rows,
-                            bounds, mask_r, *, interpret: bool = True):
+                            bounds, mask_r, *, interpret: bool):
     """Same contract as kernels/ref.partitioned_join_ref."""
     P, B = bucket_keys.shape
-    Tl_orig = keys_l.shape[0]
+    Tl = keys_l.shape[0]
+    tl = min(TILE_L, round_up(Tl, LANES))
+    tb = min(TILE_B, round_up(B, SUBLANES))
+    Tlp, Bp = round_up(Tl, tl), round_up(B, tb)
     b = jnp.searchsorted(bounds, keys_l, side="right").astype(jnp.int32) - 1
     b = jnp.clip(b, 0, P - 1)
-    cand_keys = bucket_keys[b]                        # [Tl, B]
-    cand_rows = bucket_rows[b]
-    # pad to tile multiples: padded candidates carry row -1 (never a hit),
-    # padded left rows are sliced off — mirrors bitmask_join's padding
-    tl = min(TILE_L, max(Tl_orig, 1))
-    tb = min(TILE_B, max(B, 1))
-    pad_l = (-Tl_orig) % tl
-    pad_b = (-B) % tb
-    if pad_l:
-        keys_l = jnp.pad(keys_l, (0, pad_l))
-        cand_keys = jnp.pad(cand_keys, ((0, pad_l), (0, 0)))
-        cand_rows = jnp.pad(cand_rows, ((0, pad_l), (0, 0)),
-                            constant_values=-1)
-    if pad_b:
-        cand_keys = jnp.pad(cand_keys, ((0, 0), (0, pad_b)))
-        cand_rows = jnp.pad(cand_rows, ((0, 0), (0, pad_b)),
-                            constant_values=-1)
-    Tl, Bp = Tl_orig + pad_l, B + pad_b
+    # pad to tile multiples BEFORE the gather, so the big candidate panes
+    # are materialized once: padded candidates carry row -1 (never a
+    # hit), padded left rows are sliced off
+    kl = jnp.pad(keys_l.astype(jnp.int32), (0, Tlp - Tl))
+    b = jnp.pad(b, (0, Tlp - Tl))
+    bk = jnp.pad(bucket_keys, ((0, 0), (0, Bp - B))).T       # [Bp, P]
+    br = jnp.pad(bucket_rows, ((0, 0), (0, Bp - B)),
+                 constant_values=-1).T
+    cand_keys = jnp.take(bk, b, axis=1)                      # [Bp, Tlp]
+    cand_rows = jnp.take(br, b, axis=1)
     rid = pl.pallas_call(
         _kernel,
-        grid=(Tl // tl, Bp // tb),
+        grid=(Tlp // tl, Bp // tb),
         in_specs=[
-            pl.BlockSpec((tl,), lambda i, j: (i,)),
-            pl.BlockSpec((tl, tb), lambda i, j: (i, j)),
-            pl.BlockSpec((tl, tb), lambda i, j: (i, j)),
+            pl.BlockSpec((1, tl), lambda i, j: (0, i)),
+            pl.BlockSpec((tb, tl), lambda i, j: (j, i)),
+            pl.BlockSpec((tb, tl), lambda i, j: (j, i)),
         ],
-        out_specs=pl.BlockSpec((tl,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Tl,), jnp.int32),
+        out_specs=pl.BlockSpec((1, tl), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, Tlp), jnp.int32),
         interpret=interpret,
-    )(keys_l, cand_keys, cand_rows)
-    rid = rid[:Tl_orig]
-    safe = jnp.clip(rid, 0, mask_r.shape[0] - 1)
-    combined = jnp.where((rid >= 0)[:, None], mask_l & mask_r[safe],
-                         jnp.uint32(0))
-    return rid, combined
+    )(kl[None, :], cand_keys, cand_rows)
+    rid = rid[0, :Tl]
+    return rid, intersect_matched(rid, mask_l, mask_r)

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.core.sharding import make_mesh
 from repro.models.common import MeshAxes
 
 
@@ -23,7 +24,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, have "
             f"{len(devices)}; the dry-run sets "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices)
 
 
 def make_axes(mesh) -> MeshAxes:

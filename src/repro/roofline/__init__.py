@@ -1,2 +1,2 @@
-from repro.roofline.analysis import (HW, parse_collectives,  # noqa: F401
-                                     roofline_terms, model_flops)
+from repro.roofline.analysis import (PEAKS, parse_collectives,  # noqa: F401
+                                     peaks, roofline_terms, model_flops)

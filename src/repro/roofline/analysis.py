@@ -4,8 +4,9 @@
   memory term     = HLO_bytes / (chips x HBM_bw)
   collective term = collective_bytes / (chips x link_bw)
 
-Hardware constants (TPU v5e class, per the assignment):
-  197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
+Hardware constants come from ``PEAKS``, one row per ``device_kind``
+with its published source; a device kind missing from the table is an
+error, never a silent default.
 
 Collective bytes are NOT in cost_analysis: we parse the post-SPMD
 ``compiled.as_text()`` and sum operand sizes of every all-gather /
@@ -22,11 +23,33 @@ import re
 from collections import Counter
 from typing import Dict
 
-HW = {
-    "peak_flops": 197e12,   # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,        # bytes/s per chip
-    "ici_bw": 50e9,         # bytes/s per link
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: "TPU v5 lite" is the TPU v5e — Google Cloud documentation, "TPU v5e":
+#: 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600
+#: Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,   # bf16 FLOP/s per chip
+        "peak_int8_ops": 393e12,
+        "hbm_bw": 819e9,        # bytes/s per chip
+        "hbm_bytes": 16e9,
+        "ici_bw": 50e9,         # bytes/s per link
+    },
 }
+
+#: The part the analytic models (SLA provisioning, dry-run rooflines)
+#: target when the caller names none: the v5e this repo is measured on.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str = TARGET_DEVICE_KIND) -> Dict[str, float]:
+    """The published peaks of one chip of ``device_kind``."""
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add a sourced row to "
+            "repro.roofline.analysis.PEAKS")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -112,14 +135,16 @@ def parse_collectives(hlo_text: str, default_group: int = 256) -> Dict:
 
 
 def roofline_terms(flops: float, bytes_accessed: float,
-                   collective_bytes: float, n_chips: int) -> Dict:
+                   collective_bytes: float, n_chips: int,
+                   device_kind: str = TARGET_DEVICE_KIND) -> Dict:
     """flops / bytes_accessed are GLOBAL (summed over chips);
     collective_bytes is global link traffic (per-link traffic x chips) so
     the spec formula collective_bytes/(chips*link_bw) equals per-link time.
     """
-    t_comp = flops / (n_chips * HW["peak_flops"])
-    t_mem = bytes_accessed / (n_chips * HW["hbm_bw"])
-    t_coll = collective_bytes / (n_chips * HW["ici_bw"])
+    hw = peaks(device_kind)
+    t_comp = flops / (n_chips * hw["peak_flops"])
+    t_mem = bytes_accessed / (n_chips * hw["hbm_bw"])
+    t_coll = collective_bytes / (n_chips * hw["ici_bw"])
     terms = {"compute_s": t_comp, "memory_s": t_mem, "collective_s": t_coll}
     dom = max(terms, key=terms.get)
     bound = max(t_comp, t_mem, t_coll)

@@ -119,10 +119,10 @@ class ElasticMeshManager:
                 f"mesh shape {shape} needs {n} devices, only "
                 f"{len(pool)} alive")
         pool = pool[:n]
+        from repro.core.sharding import make_mesh
         if shape[0] > 1:
-            return jax.make_mesh(shape, ("pod", "data", "model"),
-                                 devices=pool)
-        return jax.make_mesh(shape[1:], ("data", "model"), devices=pool)
+            return make_mesh(shape, ("pod", "data", "model"), pool)
+        return make_mesh(shape[1:], ("data", "model"), pool)
 
     def shrink_plan(self, current: Tuple[int, ...], chips_alive: int,
                     global_batch: Optional[int] = None) -> dict:

@@ -10,7 +10,6 @@ EXPECT = "jaxpr-delta-collective"
 
 def findings(ctx):
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.analysis_static.jaxpr_passes import lint_delta_collectives
@@ -21,10 +20,10 @@ def findings(ctx):
     def mutant(state, carry, queries, updates):
         out = delta(state, carry, queries, updates)
         words = next(iter(carry["scan"].values()))
-        gathered = shard_map(
+        gathered = jax.shard_map(
             lambda w: jax.lax.all_gather(w, spec.axis),
             mesh=spec.mesh, in_specs=P(spec.axis),
-            out_specs=P(), check_rep=False)(words)
+            out_specs=P(), check_vma=False)(words)
         return out, gathered.sum()
 
     jx = jax.make_jaxpr(mutant)(*sh["args_delta"])

@@ -47,7 +47,7 @@ def test_delta_join_kernel_parity_padded_tails(seed, Tr, Tl, n_parts,
         np.concatenate([np.arange(Tl), [-1, Tl, Tl + 5, Tl]]), D),
         jnp.int32)
     want = ref.delta_join_ref(keys_l, rows, *parts)
-    got = delta_join_pallas(keys_l, rows, *parts)
+    got = delta_join_pallas(keys_l, rows, *parts, interpret=True)
     assert (np.asarray(got) == np.asarray(want)).all()
     # fresh rids agree with the FULL partitioned probe at those rows
     W = 2

@@ -22,7 +22,7 @@ def test_clockscan_matches_ref(C, T, Q):
     lo = jnp.asarray(RNG.integers(-60, 50, (C, Q)), jnp.int32)
     hi = lo + jnp.asarray(RNG.integers(0, 80, (C, Q)), jnp.int32)
     valid = jnp.asarray(RNG.random(T) > 0.15)
-    got = clockscan_pallas(cols, lo, hi, valid)
+    got = clockscan_pallas(cols, lo, hi, valid, interpret=True)
     want = ref.clockscan_ref(cols, lo, hi, valid)
     assert (np.asarray(got) == np.asarray(want)).all()
 
@@ -34,7 +34,7 @@ def test_clockscan_bounds_inclusive():
     valid = jnp.ones(3, bool)
     got = np.asarray(clockscan_pallas(
         jnp.pad(cols, ((0, 0), (0, 253))), lo, hi,
-        jnp.pad(valid, (0, 253))))
+        jnp.pad(valid, (0, 253)), interpret=True))
     bits = got[:3, 0] & 1
     assert bits.tolist() == [1, 1, 0]
 
@@ -48,7 +48,8 @@ def test_bitmask_join_matches_ref(Tl, Tr, W):
     mask_l = jnp.asarray(RNG.integers(0, 2**32, (Tl, W)), jnp.uint32)
     mask_r = jnp.asarray(RNG.integers(0, 2**32, (Tr, W)), jnp.uint32)
     valid_r = jnp.asarray(RNG.random(Tr) > 0.25)
-    r1, m1 = bitmask_join_pallas(keys_l, mask_l, keys_r, mask_r, valid_r)
+    r1, m1 = bitmask_join_pallas(keys_l, mask_l, keys_r, mask_r, valid_r,
+                                 interpret=True)
     r2, m2 = ref.bitmask_join_ref(keys_l, mask_l, keys_r, mask_r, valid_r)
     assert (np.asarray(r1) == np.asarray(r2)).all()
     assert (np.asarray(m1) == np.asarray(m2)).all()
@@ -61,7 +62,7 @@ def test_shared_groupby_matches_ref(T, W, G):
     gc = jnp.asarray(RNG.integers(0, G, (T,)), jnp.int32)
     vals = jnp.asarray(RNG.integers(-20, 50, (T,)), jnp.int32)
     mask = jnp.asarray(RNG.integers(0, 2**32, (T, W)), jnp.uint32)
-    c1, s1 = shared_groupby_pallas(gc, vals, mask, G)
+    c1, s1 = shared_groupby_pallas(gc, vals, mask, G, interpret=True)
     c2, s2 = ref.shared_groupby_ref(gc, vals, mask, G)
     np.testing.assert_allclose(c1, c2, rtol=1e-6)
     np.testing.assert_allclose(s1, s2, rtol=1e-5, atol=1e-3)
@@ -80,7 +81,8 @@ def test_flash_attention_matches_ref(B, Sq, Sk, H, KV, D, causal, window,
     q = jnp.asarray(RNG.standard_normal((B, Sq, H, D)), dtype)
     k = jnp.asarray(RNG.standard_normal((B, Sk, KV, D)), dtype)
     v = jnp.asarray(RNG.standard_normal((B, Sk, KV, D)), dtype)
-    got = flash_attention_pallas(q, k, v, causal=causal, window=window)
+    got = flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                 interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -95,7 +97,8 @@ def test_flash_attention_matches_model_block_attention():
     q = jnp.asarray(RNG.standard_normal((B, S, H, D)), jnp.float32)
     k = jnp.asarray(RNG.standard_normal((B, S, KV, D)), jnp.float32)
     v = jnp.asarray(RNG.standard_normal((B, S, KV, D)), jnp.float32)
-    a = flash_attention_pallas(q, k, v, causal=True, window=0)
+    a = flash_attention_pallas(q, k, v, causal=True, window=0,
+                               interpret=True)
     b = block_attention(q, k, v, causal=True, window=0)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=2e-5, atol=1e-5)

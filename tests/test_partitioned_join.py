@@ -48,7 +48,8 @@ def _check_against_oracle(seed, Tr, Tl, W, valid_frac, bucket_cap,
     assert (np.asarray(got_rid) == np.asarray(want_rid)).all()
     assert (np.asarray(got_mask) == np.asarray(want_mask)).all()
     if pallas:
-        r2, m2 = partitioned_join_pallas(keys_l, mask_l, *parts, mask_r)
+        r2, m2 = partitioned_join_pallas(keys_l, mask_l, *parts, mask_r,
+                                         interpret=True)
         assert (np.asarray(r2) == np.asarray(want_rid)).all()
         assert (np.asarray(m2) == np.asarray(want_mask)).all()
 
@@ -110,7 +111,8 @@ def test_duplicate_valid_keys_resolve_to_max_row():
     expect = np.where(np.asarray(rid)[:, None] >= 0,
                       0xFF & np.asarray(mask_r)[np.maximum(rid, 0)], 0)
     assert (np.asarray(mask) == expect).all()
-    r2, m2 = partitioned_join_pallas(keys_l, mask_l, *parts, mask_r)
+    r2, m2 = partitioned_join_pallas(keys_l, mask_l, *parts, mask_r,
+                                     interpret=True)
     assert (np.asarray(r2) == np.asarray(rid)).all()
     assert (np.asarray(m2) == np.asarray(mask)).all()
 

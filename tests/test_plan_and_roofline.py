@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core.plan import compile_plan
-from repro.roofline.analysis import (HW, model_flops, parse_collectives,
+from repro.roofline.analysis import (model_flops, parse_collectives, peaks,
                                      roofline_terms)
 from repro.workloads import tpcw
 
@@ -97,6 +97,20 @@ def test_roofline_terms_dominance():
                         collective_bytes=0, n_chips=256)
     assert t2["dominant"] == "memory"
     assert 0 < t2["roofline_fraction"] < 0.01
+
+
+def test_peaks_keyed_by_device_kind_and_unknown_kind_raises():
+    from repro.core.sla import HwModel
+    v5e = peaks("TPU v5 lite")
+    assert (v5e["peak_flops"], v5e["hbm_bw"]) == (197e12, 819e9)
+    hw = HwModel.for_device()
+    assert (hw.flops_per_s, hw.bytes_per_s) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+    with pytest.raises(KeyError):
+        roofline_terms(1e12, 1e12, 0, 1, device_kind="TPU v0")
+    with pytest.raises(KeyError):
+        HwModel.for_device("cpu")
 
 
 def test_model_flops_moe_counts_active_only():
