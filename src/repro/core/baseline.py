@@ -48,7 +48,6 @@ class QueryAtATimeEngine:
             self._fns[name] = jax.jit(fn) if jit else fn
             self._param_bufs[name] = np.zeros((max(len(tpl.preds), 1), 2),
                                               np.int32)
-        self.queries_done = 0
 
     def _cap_for(self, tpl: QueryTemplate) -> int:
         spine_cap = self.plan.catalog.schemas[tpl.spine].capacity
@@ -149,15 +148,14 @@ class QueryAtATimeEngine:
         arr = self._param_bufs[template]
         for pi in range(len(tpl.preds)):
             arr[pi] = params[pi]
-        t = Ticket(0, template, params, time.time())
+        t = Ticket(0, template, params, time.perf_counter())
         t.result = self._fns[template](self.state, jnp.array(arr))
         return t
 
     def collect(self, t: Ticket) -> Ticket:
         """Block on a dispatched query and materialize its result."""
         t.result = jax.tree.map(np.asarray, t.result)
-        t.done_time = time.time()
-        self.queries_done += 1
+        t.done_time = time.perf_counter()
         return t
 
     def execute(self, template: str, params: Dict) -> Ticket:

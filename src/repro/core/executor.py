@@ -50,6 +50,7 @@ of the admission buffers for the same reason.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import os
@@ -60,11 +61,12 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import folding
 from repro.core.backends import counting_backend, resolve_backend
 from repro.core.lowering import (PARTITIONED_MIN_CAPACITY, build_cycle,
-                                 build_delta_cycle, lower_plan)
+                                 build_delta_cycle, lower_plan, stage_map)
 from repro.core.plan import CompiledPlan, QueryTemplate
 from repro.core.storage import (UPDATE_BATCH_RESET, UpdateSlots,
                                 empty_update_batch)
@@ -109,18 +111,27 @@ def _measure_key_stats(plan: CompiledPlan,
     return stats
 
 
-def _clear_counts_at_entry(fn, counts: Dict[str, int]):
+def _clear_counts_at_entry(fn, counts: Dict[str, int], name: str):
     """Reset a flavour's backend-op counter when its cycle (re)traces.
 
     Backend ops fire at TRACE time under jit, so the counts are the
     per-beat STATIC launch counts of the traced cycle; clearing at
     traced-function entry makes retraces overwrite rather than
     accumulate.  With ``jit=False`` every call re-enters, so the counts
-    are per-call either way."""
+    are per-call either way.  ``name`` names the jitted module
+    (``jit_<name>``), so a device trace says which flavour ran."""
     def wrapped(*args):
         counts.clear()
         return fn(*args)
+    wrapped.__name__ = wrapped.__qualname__ = name
     return wrapped
+
+
+def _arg_spec(x) -> jax.ShapeDtypeStruct:
+    """The abstract value a jitted call saw for one argument leaf (an
+    uncommitted array lowers without a sharding)."""
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=x.sharding if x.committed else None)
 
 
 @dataclasses.dataclass
@@ -135,6 +146,39 @@ class Ticket:
     @property
     def latency(self) -> float:
         return (self.done_time - self.submit_time) if self.done_time else None
+
+
+class _BeatSpans:
+    """One beat's host phases, each recorded as ``(name, start, end)`` on
+    ``time.perf_counter`` and tagged with the beat's sequence number
+    ``seq``.  Every span is also a ``jax.profiler.TraceAnnotation``
+    (argument ``beat=seq``), so a running profiler trace holds it on its
+    own clock; with no trace running it costs about a microsecond.
+
+    The spans of a beat: ``beat.admit`` (with ``beat.fill``, the queue
+    drain and staging-buffer fill, and ``beat.h2d``, every host-to-device
+    copy, inside it), ``beat.launch``, ``beat.wait`` (the collect-side
+    ``block_until_ready``) and ``beat.route`` (result assembly,
+    device-to-host copies, ticket routing)."""
+
+    __slots__ = ("seq", "spans")
+
+    def __init__(self, seq: int):
+        self.seq = seq
+        self.spans: List[tuple] = []
+
+    @contextlib.contextmanager
+    def __call__(self, name: str):
+        """Time the block as span ``name``; yields its start."""
+        start = time.perf_counter()
+        try:
+            with TraceAnnotation(name, beat=self.seq):
+                yield start
+        finally:
+            self.spans.append((name, start, time.perf_counter()))
+
+    def seconds(self, name: str) -> float:
+        return sum(e - s for n, s, e in self.spans if n == name)
 
 
 class _StagingBuffers:
@@ -191,12 +235,15 @@ class CycleResult:
     benchmarks and the SLA gate need to split cycle time between the
     paths.
 
-    The ``t_*_s`` fields are the beat's per-phase host-time breakdown —
-    staging (queue drain + buffer fill + H2D), dispatch (the async
-    cycle launch), kernel (the collect-side block_until_ready wait) and
-    collect (result assemble + ticket routing) — and ``backend_ops``
-    its per-op backend launch counts (from the traced cycle), so the
-    fused path's one-launch claim is machine-checkable per beat."""
+    The ``t_*_s`` fields are the beat's per-phase host-time breakdown,
+    the lengths of its spans (``_BeatSpans``) — staging
+    (``beat.admit``: queue drain + buffer fill + H2D), dispatch
+    (``beat.launch``, the async cycle launch), kernel (``beat.wait``,
+    the collect-side block_until_ready wait) and collect
+    (``beat.route``: result assemble + ticket routing) — and
+    ``backend_ops`` its per-op backend launch counts (from the traced
+    cycle), so the fused path's one-launch claim is machine-checkable
+    per beat."""
     tickets: Dict[str, List[Ticket]]
     wall_s: float
     admitted: int = 0
@@ -269,15 +316,15 @@ class _InFlight:
     """One dispatched-but-not-collected heartbeat."""
     admitted: Dict[str, List[Ticket]]
     results: Any
+    spans: _BeatSpans
     merged: Any = None          # sharded: device merge, launched at dispatch
     n_admitted: int = 0
     n_dirty: int = 0
     scan_path: str = "full"
     join_path: str = ""
-    t_stage_s: float = 0.0
-    t_dispatch_s: float = 0.0
-    t_kernel_s: float = 0.0
-    t_collect_s: float = 0.0
+    queue_wait_sum: float = 0.0   # admitted tickets' waits in the queue
+    queue_wait_max: float = 0.0
+    held_writes: int = 0          # writes put back for want of slots
     backend_ops: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
@@ -348,7 +395,7 @@ class SharedDBEngine:
         self._spilled: Dict[str, List[Ticket]] = {}
         self._spilled_stats: List[_InFlight] = []
         self.cycles_run = 0
-        self.queries_done = 0
+        self._beat_seq = itertools.count()
         self.last_overflow = 0    # union-cap overflow of the last collect
         self.delta_cycles = 0     # heartbeats dispatched down each path
         self.full_cycles = 0
@@ -358,11 +405,7 @@ class SharedDBEngine:
         self.last_join_path = ""
         self.last_delta_overflow = 0   # defensive invariant (always 0)
         self.last_parts_rebuilt: Dict[str, bool] = {}
-        self.last_collect_stats = {"admitted": 0, "dirty": 0,
-                                   "scan_path": "", "join_path": "",
-                                   "t_stage_s": 0.0, "t_dispatch_s": 0.0,
-                                   "t_kernel_s": 0.0, "t_collect_s": 0.0,
-                                   "backend_ops": {}}
+        self.last_collect_stats = self._collect_stats([])
 
     # --------------------------------------------- compiled-cycle handle
     def _build_compiled(self, plan: CompiledPlan) -> _CompiledHandle:
@@ -407,10 +450,13 @@ class SharedDBEngine:
                                         delta_joins=True)
             device_merge, assemble = None, None
             stage = jnp.asarray
-        cycle = _clear_counts_at_entry(cycle, backend_ops["full"])
-        delta = _clear_counts_at_entry(delta, backend_ops["delta"])
+        cycle = _clear_counts_at_entry(cycle, backend_ops["full"],
+                                       "beat_full")
+        delta = _clear_counts_at_entry(delta, backend_ops["delta"],
+                                       "beat_delta")
         delta_j = _clear_counts_at_entry(delta_j,
-                                         backend_ops["delta_join"])
+                                         backend_ops["delta_join"],
+                                         "beat_delta_join")
         # donate storage: the snapshot rolls forward functionally in
         # place; the delta cycles additionally donate the carried scan
         # words + key partitions (each carry is produced by one heartbeat
@@ -458,6 +504,9 @@ class SharedDBEngine:
         self._stage = h.stage
         self._carried_joins = h.carried_joins
         self._layout_token = h.layout_token
+        # flavour -> argument shapes of its first dispatch under this
+        # generation (``beat_hlo`` lowers each flavour from them again)
+        self._beat_args: Dict[str, Any] = {}
 
     # ------------------------------------------------------ plan folding
     def begin_fold(self, new_templates: List[QueryTemplate],
@@ -625,7 +674,7 @@ class SharedDBEngine:
         """Mint a ticket WITHOUT enqueueing it (serving front ends hold
         tickets for templates still waiting on a fold batch)."""
         return Ticket(next(self._ticket_ids), template, params,
-                      time.time())
+                      time.perf_counter())
 
     def accepts(self, template: str) -> bool:
         """True iff the engine has an admission queue for the template
@@ -647,15 +696,18 @@ class SharedDBEngine:
         return len(self._inflight)
 
     # ------------------------------------------------------------ one beat
-    def _admit_queries(self, buf: _StagingBuffers):
+    def _admit_queries(self, buf: _StagingBuffers, flight: _InFlight,
+                       t_admit: float) -> None:
         """Drain the queues into the packed staging buffers.
 
         Fills each admitted query's static slot range in the shared
-        [qcap, P_max, 2] / [qcap] buffers, then stages BOTH with one
-        ``jnp.asarray`` each — a single H2D copy per heartbeat instead of
-        one per template."""
-        admitted = {}
+        [qcap, P_max, 2] / [qcap] buffers (``dispatch`` then stages BOTH
+        with one copy each — a single H2D copy per heartbeat instead of
+        one per template), and records in ``flight`` the admitted
+        tickets and how long each waited in its queue: ``t_admit`` less
+        its submit time."""
         params, active = buf.params, buf.active
+        wait_sum = wait_max = 0.0
         for name, tpl in self.plan.templates.items():
             cap = self.plan.caps[name]
             off = self.plan.offsets[name]
@@ -670,12 +722,19 @@ class SharedDBEngine:
                     lo, hi = ticket.params[pi]
                     params[g, pi, 0] = lo
                     params[g, pi, 1] = hi
-            admitted[name] = take
-        batch = {"params": self._stage(params),
-                 "active": self._stage(active)}
-        return batch, admitted
+                wait = t_admit - ticket.submit_time
+                wait_sum += wait
+                wait_max = max(wait_max, wait)
+            flight.admitted[name] = take
+        flight.n_admitted = sum(len(ts) for ts in flight.admitted.values())
+        flight.queue_wait_sum, flight.queue_wait_max = wait_sum, wait_max
 
-    def _admit_updates(self, buf: _StagingBuffers):
+    def _admit_updates(self, buf: _StagingBuffers,
+                       flight: _InFlight) -> Dict[str, int]:
+        """Drain the update queue into the staging batches, in arrival
+        order; a write whose table has no free slot of its kind is held
+        for the next beat (counted in ``flight.held_writes``).  Returns
+        the per-table admitted touch counts."""
         cat = self.plan.catalog
         s = self.update_slots
         np_batches = buf.updates
@@ -713,11 +772,13 @@ class SharedDBEngine:
                 b["del_mask"][i] = True
                 f["del"] += 1
         self._update_queue = hold
+        flight.held_writes = len(hold)
         # per-table admitted touch counts: an exact upper bound on the
         # rows this batch can dirty (delta-path eligibility + accounting)
         touches = {t: f["ins"] + f["upd"] + f["del"]
                    for t, f in fill.items()}
-        return jax.tree.map(self._stage, np_batches), touches
+        flight.n_dirty = sum(touches.values())
+        return touches
 
     # -------------------------------------------------- incremental scans
     def _diff_admission(self, buf: _StagingBuffers) -> np.ndarray:
@@ -790,24 +851,33 @@ class SharedDBEngine:
         while len(self._inflight) >= self.pipeline_depth:
             for name, tickets in self._collect_oldest().items():
                 self._spilled.setdefault(name, []).extend(tickets)
-        t0 = time.perf_counter()
+        spans = _BeatSpans(next(self._beat_seq))
+        flight = _InFlight({}, None, spans=spans)
         buf = self._staging[self._staging_idx]
         self._staging_idx = (self._staging_idx + 1) % len(self._staging)
-        buf.reset()
-        queries, admitted = self._admit_queries(buf)
-        updates, touches = self._admit_updates(buf)
-        # incremental-scan path choice, made HOST-side so the traced
-        # delta cycle never contains the full-table compare: eligible
-        # when the carried words exist and every delta fits its fixed
-        # capacity, else a safe full rescan (which reseeds the carry)
-        changed = self._diff_admission(buf)
-        force_full, self._force_full = self._force_full, False
-        use_delta = (not force_full and self.delta_scans
-                     and self._carry is not None
-                     and self._delta_eligible(changed, touches))
-        use_delta_join = (use_delta and self.delta_joins
-                          and self._join_delta_eligible(touches))
-        t_staged = time.perf_counter()
+        with spans("beat.admit") as t_admit:
+            with spans("beat.fill"):
+                buf.reset()
+                self._admit_queries(buf, flight, t_admit)
+                touches = self._admit_updates(buf, flight)
+                # incremental-scan path choice, made HOST-side so the
+                # traced delta cycle never contains the full-table
+                # compare: eligible when the carried words exist and
+                # every delta fits its fixed capacity, else a safe full
+                # rescan (which reseeds the carry)
+                changed = self._diff_admission(buf)
+                force_full, self._force_full = self._force_full, False
+                use_delta = (not force_full and self.delta_scans
+                             and self._carry is not None
+                             and self._delta_eligible(changed, touches))
+                use_delta_join = (use_delta and self.delta_joins
+                                  and self._join_delta_eligible(touches))
+            with spans("beat.h2d"):
+                queries = {"params": self._stage(buf.params),
+                           "active": self._stage(buf.active)}
+                if use_delta:
+                    queries["changed"] = self._stage(changed)
+                updates = jax.tree.map(self._stage, buf.updates)
         if use_delta:
             # carry-invalidation audit: a delta heartbeat must never
             # consume a carry produced under a different admission
@@ -817,25 +887,29 @@ class SharedDBEngine:
             # without resetting the carries.  An always-on RuntimeError,
             # not an assert: ``python -O`` must not strip it.
             check_carry_layout(self._carry_token, self._layout_token)
-            queries = dict(queries, changed=self._stage(changed))
             if use_delta_join:
-                self.state, self._carry, results = self._cycle_delta_join(
-                    self.state, self._carry, self._rid_carry, queries,
-                    updates)
+                flavour, cycle = "delta_join", self._cycle_delta_join
+                args = (self.state, self._carry, self._rid_carry, queries,
+                        updates)
             else:
-                self.state, self._carry, results = self._cycle_delta(
-                    self.state, self._carry, queries, updates)
+                flavour, cycle = "delta", self._cycle_delta
+                args = (self.state, self._carry, queries, updates)
+        else:
+            flavour, cycle = "full", self._cycle
+            args = (self.state, queries, updates)
+        if self._jit and flavour not in self._beat_args:
+            self._beat_args[flavour] = jax.tree.map(_arg_spec, args)
+        with spans("beat.launch"):
+            self.state, self._carry, results = cycle(*args)
+            if self._device_merge is not None:
+                # launch the on-device cross-shard merge right behind
+                # the cycle (async); collect only blocks + copies
+                flight.merged = self._device_merge(results["_shard"])
+        flight.results = results
+        if use_delta:
             self.delta_cycles += 1
         else:
-            self.state, self._carry, results = self._cycle(
-                self.state, queries, updates)
             self.full_cycles += 1
-        merged = None
-        if self._device_merge is not None:
-            # launch the on-device cross-shard merge right behind the
-            # cycle (async); collect only blocks + copies
-            merged = self._device_merge(results["_shard"])
-        t_launched = time.perf_counter()
         # both carry halves are (re)seeded by EVERY heartbeat: the
         # scan/parts half from the cycle's carry output, the rid half
         # from the results (full-probe heartbeats — including every full
@@ -851,17 +925,10 @@ class SharedDBEngine:
                 self.full_join_cycles += 1
         self._prev_params[...] = buf.params
         self._prev_active[...] = buf.active
-        flavour = ("delta_join" if use_delta_join else "delta") \
-            if use_delta else "full"
-        self._inflight.append(_InFlight(
-            admitted, results, merged=merged,
-            n_admitted=sum(len(ts) for ts in admitted.values()),
-            n_dirty=sum(touches.values()),
-            scan_path=self.last_scan_path,
-            join_path=self.last_join_path,
-            t_stage_s=t_staged - t0,
-            t_dispatch_s=t_launched - t_staged,
-            backend_ops=dict(self.backend_ops[flavour])))
+        flight.scan_path = self.last_scan_path
+        flight.join_path = self.last_join_path
+        flight.backend_ops = dict(self.backend_ops[flavour])
+        self._inflight.append(flight)
 
     def collect(self) -> Dict[str, List[Ticket]]:
         """Block on the oldest in-flight heartbeat and route its results.
@@ -875,27 +942,43 @@ class SharedDBEngine:
         for name, tickets in self._collect_oldest().items():
             out.setdefault(name, []).extend(tickets)
         stats, self._spilled_stats = self._spilled_stats, []
+        self.last_collect_stats = self._collect_stats(stats)
+        return out
 
+    @staticmethod
+    def _collect_stats(stats: List[_InFlight]) -> Dict[str, Any]:
+        """The counters of the heartbeats one collect surfaced.  The
+        ``t_*_s`` phase times and ``h2d_s`` are span lengths; ``spans``
+        holds every span as ``(beat seq, name, start, end)``."""
         def one_path(paths):
             paths = {p for p in paths if p}
             return (paths.pop() if len(paths) == 1
                     else "mixed" if paths else "")
 
+        def span_s(name):
+            return sum(f.spans.seconds(name) for f in stats)
+
         ops: Dict[str, int] = {}
         for f in stats:
             for op, n in f.backend_ops.items():
                 ops[op] = ops.get(op, 0) + n
-        self.last_collect_stats = {
+        return {
             "admitted": sum(f.n_admitted for f in stats),
             "dirty": sum(f.n_dirty for f in stats),
             "scan_path": one_path(f.scan_path for f in stats),
             "join_path": one_path(f.join_path for f in stats),
-            "t_stage_s": sum(f.t_stage_s for f in stats),
-            "t_dispatch_s": sum(f.t_dispatch_s for f in stats),
-            "t_kernel_s": sum(f.t_kernel_s for f in stats),
-            "t_collect_s": sum(f.t_collect_s for f in stats),
+            "t_stage_s": span_s("beat.admit"),
+            "t_dispatch_s": span_s("beat.launch"),
+            "t_kernel_s": span_s("beat.wait"),
+            "t_collect_s": span_s("beat.route"),
+            "h2d_s": span_s("beat.h2d"),
+            "queue_wait_s_sum": sum(f.queue_wait_sum for f in stats),
+            "queue_wait_s_max": max((f.queue_wait_max for f in stats),
+                                    default=0.0),
+            "held_writes": sum(f.held_writes for f in stats),
+            "spans": [(f.spans.seq, *sp) for f in stats
+                      for sp in f.spans.spans],
             "backend_ops": ops}
-        return out
 
     def _collect_oldest(self) -> Dict[str, List[Ticket]]:
         if not self._inflight:
@@ -903,33 +986,33 @@ class SharedDBEngine:
         flight = self._inflight.popleft()
         self._spilled_stats.append(flight)
         results = flight.results
-        t0 = time.perf_counter()
-        jax.block_until_ready(results)
-        if flight.merged is not None:
-            jax.block_until_ready(flight.merged)
-        t_ready = time.perf_counter()
-        if self._assemble is not None:
-            # sharded heartbeat: the cross-shard routing pass already ran
-            # on-device (launched at dispatch); assembling the final
-            # per-template results is a device-to-host copy + passthrough
-            results = self._assemble(results, flight.merged)
-        self.last_overflow = int(results["_overflow"])
-        # full-rescan heartbeats have no delta capacities to violate, so
-        # the invariant reads 0 rather than a stale delta-cycle value
-        self.last_delta_overflow = int(results.get("_delta_overflow", 0))
-        self.last_parts_rebuilt = {
-            t: bool(v) for t, v in results["_parts_rebuilt"].items()}
-        now = time.time()
-        out = {}
-        for name, tickets in flight.admitted.items():
-            res = jax.tree.map(np.asarray, results[name])
-            for slot, ticket in enumerate(tickets):
-                ticket.result = jax.tree.map(lambda a: a[slot], res)
-                ticket.done_time = now
-            out[name] = tickets
-            self.queries_done += len(tickets)
-        flight.t_kernel_s = t_ready - t0
-        flight.t_collect_s = time.perf_counter() - t_ready
+        with flight.spans("beat.wait"):
+            jax.block_until_ready(results)
+            if flight.merged is not None:
+                jax.block_until_ready(flight.merged)
+        with flight.spans("beat.route"):
+            if self._assemble is not None:
+                # sharded heartbeat: the cross-shard routing pass already
+                # ran on-device (launched at dispatch); assembling the
+                # final per-template results is a device-to-host copy +
+                # passthrough
+                results = self._assemble(results, flight.merged)
+            self.last_overflow = int(results["_overflow"])
+            # full-rescan heartbeats have no delta capacities to violate,
+            # so the invariant reads 0 rather than a stale delta-cycle
+            # value
+            self.last_delta_overflow = int(results.get("_delta_overflow",
+                                                       0))
+            self.last_parts_rebuilt = {
+                t: bool(v) for t, v in results["_parts_rebuilt"].items()}
+            now = time.perf_counter()
+            out = {}
+            for name, tickets in flight.admitted.items():
+                res = jax.tree.map(np.asarray, results[name])
+                for slot, ticket in enumerate(tickets):
+                    ticket.result = jax.tree.map(lambda a: a[slot], res)
+                    ticket.done_time = now
+                out[name] = tickets
         self.cycles_run += 1
         return out
 
@@ -960,7 +1043,7 @@ class SharedDBEngine:
         depth = self.pipeline_depth if pipelined else 1
         done: List[CycleResult] = []
         dispatched = 0
-        t_prev = time.time()
+        t_prev = time.perf_counter()
         while len(done) < max_cycles and (self.pending() or self._inflight
                                           or self._spilled):
             while (self.pending() and dispatched < max_cycles
@@ -970,7 +1053,7 @@ class SharedDBEngine:
             if not self._inflight and not self._spilled:
                 break       # budget exhausted with work still queued
             routed = self.collect()
-            now = time.time()
+            now = time.perf_counter()
             s = self.last_collect_stats
             done.append(CycleResult(tickets=routed, wall_s=now - t_prev,
                                     admitted=s["admitted"],
@@ -984,6 +1067,31 @@ class SharedDBEngine:
                                     backend_ops=s["backend_ops"]))
             t_prev = now
         return done
+
+    # ------------------------------------------------------------ tracing
+    def beat_hlo(self) -> Dict[str, str]:
+        """{module name: compiled HLO text} of each cycle flavour the
+        installed generation has dispatched; empty when unjitted.
+
+        Each flavour is lowered again from the argument shapes of its
+        first dispatch and compiled — a hit in JAX's compilation cache
+        where one is on, else a compile of its own — so call it off the
+        serving path."""
+        cycles = {"full": self._cycle, "delta": self._cycle_delta,
+                  "delta_join": self._cycle_delta_join}
+        out = {}
+        for flavour, specs in self._beat_args.items():
+            text = cycles[flavour].lower(*specs).compile().as_text()
+            # the text opens "HloModule <name>, ..."
+            out[text.split(",", 1)[0].split()[1]] = text
+        return out
+
+    def trace_export(self) -> Dict[str, Dict[str, str]]:
+        """{module name: {HLO instruction name: ``"<stage>/<detail>"``}}
+        for each dispatched cycle flavour (``beat_hlo``): the heartbeat
+        stage (``lowering.STAGES``) of every device op a profiler trace
+        of this engine can show, by the op's instruction name."""
+        return {m: stage_map(text) for m, text in self.beat_hlo().items()}
 
     # --------------------------------------------------- host-side fetch
     def snapshot(self, table: str) -> Dict[str, np.ndarray]:

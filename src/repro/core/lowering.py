@@ -80,6 +80,7 @@ bit-identical to the cycles built here.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -211,6 +212,11 @@ class JoinStage:
     def key(self) -> Tuple[str, str, str]:
         """The stage's identity in ``results["_join_rids"]`` / rid carry."""
         return (self.spine, self.fk_col, self.pk_table)
+
+    @property
+    def label(self) -> str:
+        """The stage's name in its scope, ``<spine>.<fk>><pk table>``."""
+        return f"{self.spine}.{self.fk_col}>{self.pk_table}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -432,6 +438,76 @@ def check_extension_prefix(old: LoweredPlan, new: LoweredPlan) -> None:
 # the rid half of the widened carry travels through
 # ``results["_join_rids"]`` (distinct buffers from the donated carry, so
 # pipelined in-flight results never alias a later dispatch's donation).
+#
+# Every stage traces under a named scope ``<stage>/<detail>``
+# (``stage_scope``): the stage is one of ``STAGES`` and the detail its
+# table, join or sort key.  Scopes are metadata only — they change no op
+# and no fusion — but XLA keeps them in each compiled instruction's
+# ``op_name``, so ``stage_map`` can name the stage of every op of a
+# device trace.
+
+#: the heartbeat's stages, in execution order
+STAGES = ("apply", "partition", "scan", "fused_delta", "join", "sort",
+          "group", "route")
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)$", re.MULTILINE)
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_TUPLE = re.compile(r"\btuple\(")
+
+
+def stage_scope(stage: str, detail: str):
+    """The named scope one heartbeat stage traces under."""
+    return jax.named_scope(f"{stage}/{detail}")
+
+
+def _scope_of(op_name: str) -> Optional[str]:
+    """``"<stage>/<detail>"`` of an ``op_name``, else None."""
+    parts = op_name.split("/")
+    # the scope's two elements, then at least the primitive's name
+    for i in range(len(parts) - 2):
+        if parts[i] in STAGES:
+            return f"{parts[i]}/{parts[i + 1]}"
+    return None
+
+
+def stage_map(hlo_text: str) -> Dict[str, str]:
+    """{HLO instruction name: ``"<stage>/<detail>"``} of one compiled
+    module's text (``compiled.as_text()``).
+
+    An instruction's stage is read from its ``op_name`` metadata.  The
+    compiler makes instructions of its own — copies, the pieces it splits
+    a cumulative sum into, layout fusions — that carry no ``op_name`` or
+    only a primitive's; each such one takes the stage of its first
+    operand that has one (the text lists instructions in dependency
+    order), else of its first user that has one.  Tuples, which only
+    gather values, take none; instructions still without a stage are
+    left out."""
+    operands: Dict[str, List[str]] = {}     # in the text's order
+    stage: Dict[str, str] = {}
+    for m in _INSTRUCTION.finditer(hlo_text):
+        name, rest = m.group(1), m.group(2)
+        op = _OP_NAME.search(rest)
+        scope = _scope_of(op.group(1)) if op else None
+        if scope is not None:
+            stage[name] = scope
+        operands[name] = [] if _TUPLE.search(rest) else \
+            _OPERAND.findall(rest.split("), ", 1)[0])
+    users: Dict[str, List[str]] = {}
+    for name, ops in operands.items():
+        for o in ops:
+            users.setdefault(o, []).append(name)
+        if name not in stage:
+            first = next((stage[o] for o in ops if o in stage), None)
+            if first is not None:
+                stage[name] = first
+    for name in reversed(list(operands)):
+        if name not in stage:
+            first = next((stage[u] for u in users.get(name, ())
+                          if u in stage), None)
+            if first is not None:
+                stage[name] = first
+    return stage
 
 
 def _build_apply_phase(lowered: LoweredPlan):
@@ -458,18 +534,21 @@ def _build_apply_phase(lowered: LoweredPlan):
         # and ``rebuilt`` records which tables actually re-sorted.
         storage = dict(storage)
         for table, batch in updates.items():
-            storage[table] = apply_updates(cat.schemas[table],
-                                           storage[table], batch)
+            with stage_scope("apply", table):
+                storage[table] = apply_updates(cat.schemas[table],
+                                               storage[table], batch)
         partitions, rebuilt = {}, {}
         for table, (pk_col, n_parts, bucket_cap) in part_specs.items():
             t = storage[table]
-            if prev_parts is None:
-                partitions[table] = build_key_partitions(
-                    t[pk_col], t["_valid"], n_parts, bucket_cap)
-                rebuilt[table] = jnp.ones((), bool)
-            else:
-                partitions[table], rebuilt[table] = refresh_key_partitions(
-                    t, pk_col, n_parts, bucket_cap, prev_parts[table])
+            with stage_scope("partition", table):
+                if prev_parts is None:
+                    partitions[table] = build_key_partitions(
+                        t[pk_col], t["_valid"], n_parts, bucket_cap)
+                    rebuilt[table] = jnp.ones((), bool)
+                else:
+                    partitions[table], rebuilt[table] = \
+                        refresh_key_partitions(t, pk_col, n_parts,
+                                               bucket_cap, prev_parts[table])
         return storage, partitions, rebuilt
 
     return apply_phase
@@ -579,19 +658,22 @@ def build_cycle(lowered: LoweredPlan, backend: OperatorBackend):
         for st, covered, pidx in zip(lowered.scans, scan_covered,
                                      scan_pidx):
             tbl = storage[st.table]
-            if not st.cols:
-                # no predicated columns: the scan degenerates to
-                # valid-row x active-subscriber — skip the compare kernel
-                base = st.wlo * 32
-                act = queries["active"][base:base + st.q_window]
-                m = dq.pack(tbl["_valid"][:, None] & (act & covered)[None])
-            else:
-                _, lo, hi = _bind_predicates(st, covered, pidx, queries)
-                cols = jnp.stack([tbl[c] for c in st.cols])
-                m = backend.scan(cols, lo, hi, tbl["_valid"])
-                scan_carry[st.table] = m
-            scan_masks[st.table] = jnp.pad(m, ((0, 0),
-                                               (st.wlo, W - st.whi)))
+            with stage_scope("scan", st.table):
+                if not st.cols:
+                    # no predicated columns: the scan degenerates to
+                    # valid-row x active-subscriber — skip the compare
+                    # kernel
+                    base = st.wlo * 32
+                    act = queries["active"][base:base + st.q_window]
+                    m = dq.pack(tbl["_valid"][:, None]
+                                & (act & covered)[None])
+                else:
+                    _, lo, hi = _bind_predicates(st, covered, pidx, queries)
+                    cols = jnp.stack([tbl[c] for c in st.cols])
+                    m = backend.scan(cols, lo, hi, tbl["_valid"])
+                    scan_carry[st.table] = m
+                scan_masks[st.table] = jnp.pad(m, ((0, 0),
+                                                   (st.wlo, W - st.whi)))
 
         carry = {"scan": scan_carry, "parts": partitions}
         results = post_scan(storage, partitions, scan_masks)
@@ -681,81 +763,88 @@ def build_delta_cycle(lowered: LoweredPlan, backend: OperatorBackend,
         fused_scan_in, fused_stages = [], []
         for st, covered, pidx in zip(lowered.scans, scan_covered,
                                      scan_pidx):
-            tbl = storage[st.table]
-            base = st.wlo * 32
-            if not st.cols:
-                # degenerate scans are O(T*w) bit ops — cheaper to
-                # recompute than to track, so they carry no state (and
-                # stay outside the fused op)
-                act = queries["active"][base:base + st.q_window]
-                m = dq.pack(tbl["_valid"][:, None] & (act & covered)[None])
+            # a predicated stage's inputs to the fused op belong to it;
+            # the chained ops and degenerate scans are the scan stage
+            stage = "fused_delta" if fused and st.cols else "scan"
+            with stage_scope(stage, st.table):
+                tbl = storage[st.table]
+                base = st.wlo * 32
+                if not st.cols:
+                    # degenerate scans are O(T*w) bit ops — cheaper to
+                    # recompute than to track, so they carry no state (and
+                    # stay outside the fused op)
+                    act = queries["active"][base:base + st.q_window]
+                    m = dq.pack(tbl["_valid"][:, None] & (act & covered)[None])
+                    scan_masks[st.table] = jnp.pad(m, ((0, 0),
+                                                       (st.wlo, W - st.whi)))
+                    continue
+                _, lo, hi = _bind_predicates(st, covered, pidx, queries)
+                cols = jnp.stack([tbl[c] for c in st.cols])
+                A = st.delta_words
+
+                # admission pane: the contiguous word range holding every
+                # changed slot (recomputed over all rows at pane width) and
+                # the dirty rows (rescanned at full window width); both
+                # merge in place into the donated carry — fused in one op,
+                # or chained through scan / scan_delta / the scatter
+                span, w0, over = _pane_window(st, covered, changed)
+                delta_over += over
+                lo_a = jax.lax.dynamic_slice(lo, (0, w0 * 32),
+                                             (lo.shape[0], A * 32))
+                hi_a = jax.lax.dynamic_slice(hi, (0, w0 * 32),
+                                             (hi.shape[0], A * 32))
+                dr = tbl["_dirty_rows"]
+                delta_over += tbl["_dirty_overflow"].astype(jnp.int32)
+                if fused:
+                    fused_scan_in.append(FusedScanIn(
+                        cols=cols, lo=lo, hi=hi, lo_p=lo_a, hi_p=hi_a,
+                        valid=tbl["_valid"], carry=carry["scan"][st.table],
+                        w0=w0, span=span, rows=dr,
+                        dn=tbl["_dirty_n"].astype(jnp.int32)))
+                    fused_stages.append(st)
+                    continue
+                pane = backend.scan(cols, lo_a, hi_a, tbl["_valid"])
+                m = jax.lax.dynamic_update_slice(carry["scan"][st.table],
+                                                 pane, (0, w0))
+                dwords = backend.scan_delta(cols, lo, hi, tbl["_valid"], dr)
+                m = scatter_dirty_rows(m, dr, dwords,
+                                       cat.schemas[st.table].capacity)
+                new_carry[st.table] = m
                 scan_masks[st.table] = jnp.pad(m, ((0, 0),
                                                    (st.wlo, W - st.whi)))
-                continue
-            _, lo, hi = _bind_predicates(st, covered, pidx, queries)
-            cols = jnp.stack([tbl[c] for c in st.cols])
-            A = st.delta_words
-
-            # admission pane: the contiguous word range holding every
-            # changed slot (recomputed over all rows at pane width) and
-            # the dirty rows (rescanned at full window width); both
-            # merge in place into the donated carry — fused in one op,
-            # or chained through scan / scan_delta / the scatter
-            span, w0, over = _pane_window(st, covered, changed)
-            delta_over += over
-            lo_a = jax.lax.dynamic_slice(lo, (0, w0 * 32),
-                                         (lo.shape[0], A * 32))
-            hi_a = jax.lax.dynamic_slice(hi, (0, w0 * 32),
-                                         (hi.shape[0], A * 32))
-            dr = tbl["_dirty_rows"]
-            delta_over += tbl["_dirty_overflow"].astype(jnp.int32)
-            if fused:
-                fused_scan_in.append(FusedScanIn(
-                    cols=cols, lo=lo, hi=hi, lo_p=lo_a, hi_p=hi_a,
-                    valid=tbl["_valid"], carry=carry["scan"][st.table],
-                    w0=w0, span=span, rows=dr,
-                    dn=tbl["_dirty_n"].astype(jnp.int32)))
-                fused_stages.append(st)
-                continue
-            pane = backend.scan(cols, lo_a, hi_a, tbl["_valid"])
-            m = jax.lax.dynamic_update_slice(carry["scan"][st.table],
-                                             pane, (0, w0))
-            dwords = backend.scan_delta(cols, lo, hi, tbl["_valid"], dr)
-            m = scatter_dirty_rows(m, dr, dwords,
-                                   cat.schemas[st.table].capacity)
-            new_carry[st.table] = m
-            scan_masks[st.table] = jnp.pad(m, ((0, 0),
-                                               (st.wlo, W - st.whi)))
 
         fused_join_in = []
         if delta_joins:
             # defensive: a carried join's spine dirty set must not have
             # overflowed either (the host checks the same thing exactly)
             for spine in carried_spines:
-                delta_over += \
-                    storage[spine]["_dirty_overflow"].astype(jnp.int32)
+                with stage_scope("join", spine):
+                    delta_over += \
+                        storage[spine]["_dirty_overflow"].astype(jnp.int32)
             if fused:
                 for j in carried_joins:
                     tbl = storage[j.spine]
-                    if j.kind == "partitioned":
-                        bkeys, brows, bounds = partitions[j.pk_table]
-                    else:  # block: single-bucket pseudo-partitions
-                        bkeys, brows, bounds = _pseudo_partitions(
-                            storage[j.pk_table], j.pk_col)
-                    fused_join_in.append(FusedJoinIn(
-                        keys=tbl[j.fk_col], rows=tbl["_dirty_rows"],
-                        dn=tbl["_dirty_n"].astype(jnp.int32),
-                        bkeys=bkeys, brows=brows, bounds=bounds,
-                        rid_carry=rid_carry[j.key]))
+                    with stage_scope("fused_delta", j.label):
+                        if j.kind == "partitioned":
+                            bkeys, brows, bounds = partitions[j.pk_table]
+                        else:  # block: single-bucket pseudo-partitions
+                            bkeys, brows, bounds = _pseudo_partitions(
+                                storage[j.pk_table], j.pk_col)
+                        fused_join_in.append(FusedJoinIn(
+                            keys=tbl[j.fk_col], rows=tbl["_dirty_rows"],
+                            dn=tbl["_dirty_n"].astype(jnp.int32),
+                            bkeys=bkeys, brows=brows, bounds=bounds,
+                            rid_carry=rid_carry[j.key]))
 
         fused_rids = None
         if fused and (fused_scan_in or fused_join_in):
-            words, rids = backend.fused_delta(tuple(fused_scan_in),
-                                              tuple(fused_join_in))
-            for st, m in zip(fused_stages, words):
-                new_carry[st.table] = m
-                scan_masks[st.table] = jnp.pad(m, ((0, 0),
-                                                   (st.wlo, W - st.whi)))
+            with stage_scope("fused_delta", "op"):
+                words, rids = backend.fused_delta(tuple(fused_scan_in),
+                                                  tuple(fused_join_in))
+                for st, m in zip(fused_stages, words):
+                    new_carry[st.table] = m
+                    scan_masks[st.table] = jnp.pad(
+                        m, ((0, 0), (st.wlo, W - st.whi)))
             if delta_joins:
                 fused_rids = {j.key: r
                               for j, r in zip(carried_joins, rids)}
@@ -801,50 +890,51 @@ def _build_post_scan(lowered: LoweredPlan, backend: OperatorBackend):
         spine_masks = dict(scan_masks)
         join_rids = {}
         for st, sub in zip(lowered.joins, join_subs):
-            tbl = storage[st.spine]
-            m = spine_masks[st.spine]
-            if st.kind == "gather":
-                rid, combined = ops.shared_join_fk(
-                    tbl[st.fk_col], m,
-                    storage[st.pk_table]["_pk_index"],
-                    scan_masks[st.pk_table])
-            elif fused_rids is not None:
-                rid = fused_rids[st.key]
-                mask_r = scan_masks[st.pk_table]
-                gathered = mask_r[jnp.clip(rid, 0, mask_r.shape[0] - 1)]
-                combined = jnp.where((rid >= 0)[:, None], m & gathered,
-                                     jnp.uint32(0))
-            elif rid_carry is not None:
-                cap = cat.schemas[st.spine].capacity
-                dr = tbl["_dirty_rows"]
-                if st.kind == "partitioned":
+            with stage_scope("join", st.label):
+                tbl = storage[st.spine]
+                m = spine_masks[st.spine]
+                if st.kind == "gather":
+                    rid, combined = ops.shared_join_fk(
+                        tbl[st.fk_col], m,
+                        storage[st.pk_table]["_pk_index"],
+                        scan_masks[st.pk_table])
+                elif fused_rids is not None:
+                    rid = fused_rids[st.key]
+                    mask_r = scan_masks[st.pk_table]
+                    gathered = mask_r[jnp.clip(rid, 0, mask_r.shape[0] - 1)]
+                    combined = jnp.where((rid >= 0)[:, None], m & gathered,
+                                         jnp.uint32(0))
+                elif rid_carry is not None:
+                    cap = cat.schemas[st.spine].capacity
+                    dr = tbl["_dirty_rows"]
+                    if st.kind == "partitioned":
+                        bkeys, brows, bounds = partitions[st.pk_table]
+                        rid_d = backend.join_delta(tbl[st.fk_col], dr,
+                                                   bkeys, brows, bounds)
+                    else:  # block: dirty-row key-equality probe (tiny PK)
+                        pk_tbl = storage[st.pk_table]
+                        kd = tbl[st.fk_col][jnp.clip(dr, 0, cap - 1)]
+                        rid_d = locate_rows_by_key(pk_tbl[st.pk_col], kd,
+                                                   pk_tbl["_valid"])
+                    rid = scatter_dirty_rows(rid_carry[st.key], dr, rid_d,
+                                             cap)
+                    mask_r = scan_masks[st.pk_table]
+                    gathered = mask_r[jnp.clip(rid, 0, mask_r.shape[0] - 1)]
+                    combined = jnp.where((rid >= 0)[:, None], m & gathered,
+                                         jnp.uint32(0))
+                elif st.kind == "partitioned":
                     bkeys, brows, bounds = partitions[st.pk_table]
-                    rid_d = backend.join_delta(tbl[st.fk_col], dr,
-                                               bkeys, brows, bounds)
-                else:  # block: dirty-row key-equality probe (tiny PK)
+                    rid, combined = backend.join_partitioned(
+                        tbl[st.fk_col], m, bkeys, brows, bounds,
+                        scan_masks[st.pk_table])
+                else:  # block: dense key-equality kernel, small index-less PK
                     pk_tbl = storage[st.pk_table]
-                    kd = tbl[st.fk_col][jnp.clip(dr, 0, cap - 1)]
-                    rid_d = locate_rows_by_key(pk_tbl[st.pk_col], kd,
-                                               pk_tbl["_valid"])
-                rid = scatter_dirty_rows(rid_carry[st.key], dr, rid_d,
-                                         cap)
-                mask_r = scan_masks[st.pk_table]
-                gathered = mask_r[jnp.clip(rid, 0, mask_r.shape[0] - 1)]
-                combined = jnp.where((rid >= 0)[:, None], m & gathered,
-                                     jnp.uint32(0))
-            elif st.kind == "partitioned":
-                bkeys, brows, bounds = partitions[st.pk_table]
-                rid, combined = backend.join_partitioned(
-                    tbl[st.fk_col], m, bkeys, brows, bounds,
-                    scan_masks[st.pk_table])
-            else:  # block: dense key-equality kernel, small index-less PK
-                pk_tbl = storage[st.pk_table]
-                rid, combined = backend.join_block(
-                    tbl[st.fk_col], m, pk_tbl[st.pk_col],
-                    scan_masks[st.pk_table], pk_tbl["_valid"])
-            spine_masks[st.spine] = (combined & sub[None, :]) \
-                | (m & ~sub[None, :])
-            join_rids[st.key] = rid
+                    rid, combined = backend.join_block(
+                        tbl[st.fk_col], m, pk_tbl[st.pk_col],
+                        scan_masks[st.pk_table], pk_tbl["_valid"])
+                spine_masks[st.spine] = (combined & sub[None, :]) \
+                    | (m & ~sub[None, :])
+                join_rids[st.key] = rid
 
         # 4. shared sorts + fused per-query top-n + routing (Gamma): the
         #    sort runs over the bounded UNION of tuples wanted by the
@@ -852,51 +942,54 @@ def _build_post_scan(lowered: LoweredPlan, backend: OperatorBackend):
         results = {}
         overflow = jnp.zeros((), jnp.int32)
         for st, sub in zip(lowered.sorts, sort_subs):
-            mask = spine_masks[st.spine][:, st.wlo:st.whi] & sub[None, :]
-            rows_c, cmask, n_want = ops.compress_union(mask, st.union_cap)
-            overflow += jnp.maximum(n_want - st.union_cap, 0)
-            keys = storage[st.spine][st.col][jnp.maximum(rows_c, 0)]
-            keys = jnp.where(rows_c >= 0,
-                             -keys if st.desc else keys, ops.INT_MAX)
-            perm = jnp.argsort(keys, stable=True)
-            rows = ops.route_topn(cmask[perm],
-                                  limits[st.wlo * 32:st.whi * 32],
-                                  plan.max_results, rows=rows_c[perm])
-            for name, o, c in st.slots:
-                results[name] = {"rows": rows[o:o + c]}
+            with stage_scope("sort", f"{st.spine}.{st.col}"):
+                mask = spine_masks[st.spine][:, st.wlo:st.whi] & sub[None, :]
+                rows_c, cmask, n_want = ops.compress_union(mask, st.union_cap)
+                overflow += jnp.maximum(n_want - st.union_cap, 0)
+                keys = storage[st.spine][st.col][jnp.maximum(rows_c, 0)]
+                keys = jnp.where(rows_c >= 0,
+                                 -keys if st.desc else keys, ops.INT_MAX)
+                perm = jnp.argsort(keys, stable=True)
+                rows = ops.route_topn(cmask[perm],
+                                      limits[st.wlo * 32:st.whi * 32],
+                                      plan.max_results, rows=rows_c[perm])
+                for name, o, c in st.slots:
+                    results[name] = {"rows": rows[o:o + c]}
 
         # 5. shared group-bys (phase 1 shared over the union, phase 2 per
         #    query)
         for st in lowered.groups:
-            agg = st.agg
-            tbl = storage[st.spine]
-            rows_c, cmask, n_want = ops.compress_union(
-                spine_masks[st.spine][:, st.wlo:st.whi], st.union_cap)
-            overflow += jnp.maximum(n_want - st.union_cap, 0)
-            safe = jnp.maximum(rows_c, 0)
-            gcodes = jnp.where(rows_c >= 0, tbl[agg.group_col][safe], 0)
-            gvals = jnp.where(rows_c >= 0, tbl[agg.agg_col][safe], 0)
-            count, ssum = backend.groupby(gcodes, gvals, cmask,
-                                          agg.n_groups)
-            score = ssum if agg.order_by == "sum" else count
-            top_val, top_grp = jax.lax.top_k(score.T, agg.top_k)  # [q, K]
-            for name, o, c in st.slots:
-                results[name] = {
-                    "groups": top_grp[o:o + c].astype(jnp.int32),
-                    "scores": top_val[o:o + c],
-                    "counts": jnp.take_along_axis(
-                        count.T[o:o + c], top_grp[o:o + c], axis=1)}
+            with stage_scope("group", f"{st.spine}.{st.agg.group_col}"):
+                agg = st.agg
+                tbl = storage[st.spine]
+                rows_c, cmask, n_want = ops.compress_union(
+                    spine_masks[st.spine][:, st.wlo:st.whi], st.union_cap)
+                overflow += jnp.maximum(n_want - st.union_cap, 0)
+                safe = jnp.maximum(rows_c, 0)
+                gcodes = jnp.where(rows_c >= 0, tbl[agg.group_col][safe], 0)
+                gvals = jnp.where(rows_c >= 0, tbl[agg.agg_col][safe], 0)
+                count, ssum = backend.groupby(gcodes, gvals, cmask,
+                                              agg.n_groups)
+                score = ssum if agg.order_by == "sum" else count
+                top_val, top_grp = jax.lax.top_k(score.T, agg.top_k)  # [q, K]
+                for name, o, c in st.slots:
+                    results[name] = {
+                        "groups": top_grp[o:o + c].astype(jnp.int32),
+                        "scores": top_val[o:o + c],
+                        "counts": jnp.take_along_axis(
+                            count.T[o:o + c], top_grp[o:o + c], axis=1)}
 
         # 6. unsorted templates route in natural row order — ONE routing
         #    pass per spine shared by all such templates
         for st, sub in zip(lowered.routes, route_subs):
-            mask = spine_masks[st.spine][:, st.wlo:st.whi] & sub[None, :]
-            rows_c, cmask, n_want = ops.compress_union(mask, st.union_cap)
-            overflow += jnp.maximum(n_want - st.union_cap, 0)
-            rows = ops.route_topn(cmask, limits[st.wlo * 32:st.whi * 32],
-                                  plan.max_results, rows=rows_c)
-            for name, o, c in st.slots:
-                results[name] = {"rows": rows[o:o + c]}
+            with stage_scope("route", st.spine):
+                mask = spine_masks[st.spine][:, st.wlo:st.whi] & sub[None, :]
+                rows_c, cmask, n_want = ops.compress_union(mask, st.union_cap)
+                overflow += jnp.maximum(n_want - st.union_cap, 0)
+                rows = ops.route_topn(cmask, limits[st.wlo * 32:st.whi * 32],
+                                      plan.max_results, rows=rows_c)
+                for name, o, c in st.slots:
+                    results[name] = {"rows": rows[o:o + c]}
         results["_overflow"] = overflow
 
         # attach join rids so hosts can materialize joined tuples
