@@ -113,6 +113,9 @@ class OperatorBackend:
     #   -> (tuple of merged uint32[T, w] words — one per scan_in entry,
     #       tuple of merged int32[Tl] rids — one per join_in entry)
     fused_delta: Optional[Callable] = None
+    # (P) -> "count" | "search": the bucket routing a join_partitioned
+    # launch over P buckets takes (None -> not counted)
+    join_route: Optional[Callable] = None
 
 
 _REGISTRY: Dict[str, OperatorBackend] = {}
@@ -216,7 +219,7 @@ register_backend(OperatorBackend(
     name="jnp", scan=_jnp_scan, join_block=_jnp_join_block,
     join_partitioned=_jnp_join_partitioned, groupby=_jnp_groupby,
     scan_delta=_jnp_scan_delta, join_delta=_jnp_join_delta,
-    fused_delta=_jnp_fused_delta))
+    fused_delta=_jnp_fused_delta, join_route=lambda P: "search"))
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +233,8 @@ _COUNTED_OPS = ("scan", "join_block", "join_partitioned", "groupby",
 def counting_backend(base: OperatorBackend, counts: Dict[str, int],
                      name: Optional[str] = None) -> OperatorBackend:
     """Wrap every op of ``base`` to bump ``counts[op]`` per invocation.
+    A ``join_partitioned`` launch also bumps ``join_route_<route>``, the
+    bucket routing ``base.join_route`` names for its bucket count.
 
     Backend ops are invoked at TRACE time (the cycles are jitted), so
     with a jitted engine the counts are the per-beat STATIC launch
@@ -245,9 +250,12 @@ def counting_backend(base: OperatorBackend, counts: Dict[str, int],
 
         def counted(*args, **kwargs):
             counts[opname] = counts.get(opname, 0) + 1
+            if opname == "join_partitioned" and base.join_route:
+                key = "join_route_" + base.join_route(args[2].shape[0])
+                counts[key] = counts.get(key, 0) + 1
             return op(*args, **kwargs)
         return counted
 
     return OperatorBackend(
-        name=name or f"counting-{base.name}",
+        name=name or f"counting-{base.name}", join_route=base.join_route,
         **{op: wrap(getattr(base, op), op) for op in _COUNTED_OPS})

@@ -42,6 +42,11 @@ def _pallas_join_partitioned(keys_l, mask_l, bucket_keys, bucket_rows,
                                    interpret=interpret_mode())
 
 
+def _pallas_join_route(n_buckets: int) -> str:
+    from repro.kernels.partitioned_join import join_route
+    return join_route(n_buckets)
+
+
 def _pallas_groupby(group_code, values, mask, n_groups: int):
     from repro.kernels.shared_groupby import shared_groupby_pallas
     return shared_groupby_pallas(group_code, values, mask, n_groups,
@@ -69,4 +74,4 @@ _backends.register_backend(_backends.OperatorBackend(
     name="pallas", scan=_pallas_scan, join_block=_pallas_join_block,
     join_partitioned=_pallas_join_partitioned, groupby=_pallas_groupby,
     scan_delta=_pallas_scan_delta, join_delta=_pallas_join_delta,
-    fused_delta=_pallas_fused_delta))
+    fused_delta=_pallas_fused_delta, join_route=_pallas_join_route))

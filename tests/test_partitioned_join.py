@@ -1,7 +1,8 @@
 """Partitioned shared join: property tests vs the dense block-join oracle
 (duplicate keys, empty buckets, all-invalid rows, capacity-boundary
-padding), jnp/pallas kernel parity, lowering access-path selection, and a
-full-engine jnp-vs-pallas parity run over index-less TPC-W."""
+padding), jnp/pallas kernel parity, the probe keys' bucket routing against
+``searchsorted``, lowering access-path selection, and a full-engine
+jnp-vs-pallas parity run over index-less TPC-W."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,7 +17,8 @@ except ImportError:
 from repro.core.executor import SharedDBEngine
 from repro.core.lowering import (PARTITIONED_MIN_CAPACITY, lower_plan,
                                  partition_layout)
-from repro.core.storage import build_key_partitions
+from repro.core.storage import INT_SENTINEL, build_key_partitions
+from repro.kernels import partitioned_join as pj
 from repro.kernels import ref
 from repro.kernels.partitioned_join import partitioned_join_pallas
 from repro.workloads import tpcw
@@ -117,6 +119,76 @@ def test_duplicate_valid_keys_resolve_to_max_row():
     assert (np.asarray(m2) == np.asarray(mask)).all()
 
 
+# ------------------------------------------------- probe-key bucket routing
+def _route_world(seed, P, Tl):
+    """Ascending bounds with runs of one key spanning several buckets and
+    an ``INT_SENTINEL`` tail (empty buckets), and probe keys below the
+    first bound, above the last, negative, extreme and equal to bounds."""
+    rng = np.random.default_rng(seed)
+    n_live = max(P - P // 5, 1)
+    live = np.sort(rng.integers(-1000, 40 * P + 1000, n_live))
+    if n_live > 6:
+        live[n_live // 3:n_live // 3 + 4] = live[n_live // 3]   # a key run
+    bounds = np.concatenate(
+        [live, np.full(P - n_live, int(INT_SENTINEL))]).astype(np.int32)
+    fixed = np.concatenate([bounds[::max(P // 7, 1)], bounds[-1:],
+                            [bounds[0] - 1, live[-1] + 1, -1, 0,
+                             -2**31, 2**31 - 1, 2**31 - 2]])
+    keys = np.concatenate([fixed, rng.integers(-2000, 40 * P + 2000,
+                                               Tl - fixed.size)])
+    return rng.permutation(keys).astype(np.int32), bounds
+
+
+@pytest.mark.parametrize("P,Tl", [(1, 129), (15, 300), (59, 1001),
+                                  (1519, 1300), (2 * pj.ROUTE_CHUNK + 5,
+                                                 777)])
+def test_route_buckets_matches_searchsorted(P, Tl):
+    """The compare-count routing (Pallas, interpret mode) gives every key
+    the bucket the search gives: clip(searchsorted(bounds, k, "right") - 1,
+    0, P-1), across bound chunks and for Tl not a tile multiple."""
+    keys, bounds = _route_world(P, P, Tl)
+    assert pj.join_route(P) == "count"
+    want = np.clip(np.searchsorted(bounds, keys, side="right") - 1, 0, P - 1)
+    got = pj.route_buckets(jnp.asarray(keys), jnp.asarray(bounds),
+                           interpret=True)
+    assert got.dtype == jnp.int32
+    assert (np.asarray(got) == want).all()
+
+
+def test_route_buckets_searches_above_the_count_limit():
+    """Above ``COUNT_ROUTE_MAX_P`` buckets the routing is the search."""
+    P = pj.COUNT_ROUTE_MAX_P + 1
+    keys, bounds = _route_world(7, P, 300)
+    assert pj.join_route(P) == "search"
+    want = np.clip(np.searchsorted(bounds, keys, side="right") - 1, 0, P - 1)
+    got = pj.route_buckets(jnp.asarray(keys), jnp.asarray(bounds),
+                           interpret=True)
+    assert (np.asarray(got) == want).all()
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_partitioned_join_pallas_matches_ref_many_buckets(seed):
+    """1,519 buckets of two rows (the order_line>orders bucket count):
+    the Pallas join's rid and combined equal the reference's bit for
+    bit, duplicates and invalid rows included."""
+    rng = np.random.default_rng(seed)
+    P, B, Tl, W = 1519, 2, 1500, 2
+    Tr = P * B - 3
+    keys_r = jnp.asarray(rng.integers(-5, Tr // 2, Tr), jnp.int32)
+    valid_r = jnp.asarray(rng.random(Tr) < 0.9)
+    keys_l = jnp.asarray(rng.integers(-10, Tr // 2 + 10, Tl), jnp.int32)
+    mask_l = jnp.asarray(rng.integers(0, 2**32, (Tl, W)), jnp.uint32)
+    mask_r = jnp.asarray(rng.integers(0, 2**32, (Tr, W)), jnp.uint32)
+    parts = build_key_partitions(keys_r, valid_r, P, B)
+    want_rid, want_mask = ref.partitioned_join_ref(keys_l, mask_l, *parts,
+                                                   mask_r)
+    rid, mask = partitioned_join_pallas(keys_l, mask_l, *parts, mask_r,
+                                        interpret=True)
+    assert (np.asarray(rid) >= 0).any()
+    assert (np.asarray(rid) == np.asarray(want_rid)).all()
+    assert (np.asarray(mask) == np.asarray(want_mask)).all()
+
+
 def test_partition_layout_covers_capacity():
     for cap in (1, 7, 255, 256, 257, 512, 4096, 10001):
         n, b = partition_layout(cap)
@@ -167,18 +239,27 @@ def indexless_world():
     return plan, data
 
 
-def test_engine_jnp_vs_pallas_parity_partitioned_tpcw(indexless_world):
-    """Acceptance: the full engine produces identical results on both
-    backends when every TPC-W join runs the partitioned access path."""
+@pytest.fixture(scope="module")
+def backend_beats(indexless_world):
+    """One beat of QUERIES on each backend: {kernels: (engine, tickets)}."""
     plan, data = indexless_world
-    assert any(j.kind == "partitioned" for j in lower_plan(plan).joins)
-    tickets = []
+    beats = {}
     for kernels in ("jnp", "pallas"):
         eng = SharedDBEngine(plan, tpcw.DEFAULT_UPDATE_SLOTS, data,
                              jit=False, kernels=kernels)
-        tickets.append([eng.submit(n, p) for n, p in QUERIES])
+        tickets = [eng.submit(n, p) for n, p in QUERIES]
         eng.run_cycle()
-    for a, b in zip(*tickets):
+        beats[kernels] = (eng, tickets)
+    return beats
+
+
+def test_engine_jnp_vs_pallas_parity_partitioned_tpcw(indexless_world,
+                                                      backend_beats):
+    """Acceptance: the full engine produces identical results on both
+    backends when every TPC-W join runs the partitioned access path."""
+    plan, _ = indexless_world
+    assert any(j.kind == "partitioned" for j in lower_plan(plan).joins)
+    for a, b in zip(backend_beats["jnp"][1], backend_beats["pallas"][1]):
         assert a.template == b.template
         if "rows" in a.result:
             assert (np.asarray(a.result["rows"])
@@ -189,6 +270,22 @@ def test_engine_jnp_vs_pallas_parity_partitioned_tpcw(indexless_world):
             np.testing.assert_allclose(np.asarray(a.result["scores"]),
                                        np.asarray(b.result["scores"]),
                                        rtol=1e-5)
+
+
+def test_engine_counts_each_partitioned_stages_route(indexless_world,
+                                                    backend_beats):
+    """The full beat's backend-op counters name the routing of every
+    partitioned join: the compare-count on the Pallas backend, the search
+    on the jnp reference."""
+    plan, _ = indexless_world
+    n = sum(j.kind == "partitioned" for j in lower_plan(plan).joins)
+    assert n == 4
+    ops = backend_beats["pallas"][0].backend_ops["full"]
+    assert ops["join_partitioned"] == ops["join_route_count"] == n, ops
+    assert "join_route_search" not in ops
+    ops = backend_beats["jnp"][0].backend_ops["full"]
+    assert ops["join_partitioned"] == ops["join_route_search"] == n, ops
+    assert "join_route_count" not in ops
 
 
 def test_partitioned_engine_matches_query_at_a_time(indexless_world):

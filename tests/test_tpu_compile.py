@@ -99,20 +99,50 @@ def test_shared_groupby_compiles(one_chip, lowered):
              _struct(one_chip, (T, W), jnp.uint32))
 
 
-def test_partitioned_join_compiles(one_chip, lowered):
-    """order_line -> orders: the widest spine and the most buckets."""
+def _search_loops(compiled, n_keys):
+    """The compiled program's ``while`` loops that carry an s32[n_keys]
+    array: ``searchsorted``'s gather rounds over the probe keys."""
+    return [ln for ln in compiled.as_text().splitlines()
+            if " while(" in ln and f"s32[{n_keys}]" in ln.split(" while(")[0]]
+
+
+def _compile_partitioned_join(one_chip, lowered, n_partitions=None):
     from repro.kernels.partitioned_join import partitioned_join_pallas
     j = _join(lowered, "order_line", "orders")
     Tl, Tr, W = _cap(lowered, "order_line"), _cap(lowered, "orders"), \
         lowered.W
-    P, B = j.n_partitions, j.bucket_cap
-    _compile(lambda kl, ml, bk, br, bd, mr: partitioned_join_pallas(
-                 kl, ml, bk, br, bd, mr, interpret=False),
-             _struct(one_chip, (Tl,)),
-             _struct(one_chip, (Tl, W), jnp.uint32),
-             _struct(one_chip, (P, B)), _struct(one_chip, (P, B)),
-             _struct(one_chip, (P,)),
-             _struct(one_chip, (Tr, W), jnp.uint32))
+    P, B = n_partitions or j.n_partitions, j.bucket_cap
+    return Tl, P, _compile(
+        lambda kl, ml, bk, br, bd, mr: partitioned_join_pallas(
+            kl, ml, bk, br, bd, mr, interpret=False),
+        _struct(one_chip, (Tl,)),
+        _struct(one_chip, (Tl, W), jnp.uint32),
+        _struct(one_chip, (P, B)), _struct(one_chip, (P, B)),
+        _struct(one_chip, (P,)),
+        _struct(one_chip, (Tr, W), jnp.uint32))
+
+
+def test_partitioned_join_compiles(one_chip, lowered):
+    """order_line -> orders: the widest spine and the most buckets.  Its
+    probe keys are routed by the compare-count kernel, so no search loop
+    over the 1,166,400 keys is left in the program."""
+    from repro.kernels.partitioned_join import join_route
+    Tl, P, compiled = _compile_partitioned_join(one_chip, lowered)
+    assert join_route(P) == "count"
+    # the routing kernel and the match kernel
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2
+    assert not _search_loops(compiled, Tl)
+
+
+def test_partitioned_join_search_route_compiles(one_chip, lowered):
+    """Above ``COUNT_ROUTE_MAX_P`` buckets the same join compiles with
+    the search's loop."""
+    from repro.kernels.partitioned_join import COUNT_ROUTE_MAX_P, join_route
+    Tl, P, compiled = _compile_partitioned_join(
+        one_chip, lowered, COUNT_ROUTE_MAX_P + 1)
+    assert join_route(P) == "search"
+    assert _search_loops(compiled, Tl)
 
 
 def test_bitmask_join_compiles(one_chip, lowered):
