@@ -1,0 +1,9 @@
+"""apply_dev_ms.sat: device time per beat in the heartbeat's update-apply
+and key-partition stages (scopes ``apply/*`` and ``partition/*``), ms,
+over the whole ``jit_beat_*`` executions in the traced window
+(``program_trace.stage_split`` with the engine's ``trace_export()``)."""
+from program_trace import stage_group
+
+
+def read(run):
+    return stage_group(run, "apply_dev_ms")

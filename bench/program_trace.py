@@ -1,11 +1,10 @@
 """Reduce a JAX profiler trace with the program's own names.
 
-``trace_reduce`` keeps the device's ops and the harness's host spans.
-This module reads the rest of the ``.xplane.pb``: the first device's
-``XLA Modules`` events (one per heartbeat, named ``jit_beat_full``,
-``jit_beat_delta`` or ``jit_beat_delta_join`` by ``SharedDBEngine``) and
-the engine's ``beat.*`` host spans.  The traced window is the harness's
-``trace_window`` span, as in ``trace_reduce``.
+Reads what ``trace_reduce`` keeps of the ``.xplane.pb`` (a ``Trace``):
+the first device's ops in the traced window, its ``XLA Modules`` events
+(one per heartbeat, named ``jit_beat_full``, ``jit_beat_delta`` or
+``jit_beat_delta_join`` by ``SharedDBEngine``) and the engine's
+``beat.*`` host spans.
 
 * stage time: with the engine's op-to-stage map
   (``SharedDBEngine.trace_export()``: {module: {HLO instruction:
@@ -28,11 +27,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Tuple
 
-from trace_reduce import (WINDOW_SPAN, _device_planes, _op_line, clip,
-                          union_ns)
+from trace_reduce import idle_ns, union_ns
 
 BEAT_MODULE = "jit_beat_"
-PROGRAM_SPAN = "beat."
 EDGE_NS = 1000.0
 #: the per-layer groups of the heartbeat's stages (``lowering.STAGES``)
 STAGE_GROUPS = {"apply_dev_ms": ("apply", "partition"),
@@ -55,15 +52,6 @@ class StageSplit:
                for g, stages in STAGE_GROUPS.items()}
         out["stage_cover"] = self.cover
         return out
-
-
-def window_ns(data) -> Tuple[float, float]:
-    wins = [(float(ev.start_ns), float(ev.start_ns + ev.duration_ns))
-            for p in data.planes if p.name.startswith("/host:")
-            for ln in p.lines for ev in ln.events if ev.name == WINDOW_SPAN]
-    if not wins:
-        raise ValueError(f"trace has no {WINDOW_SPAN!r} host span")
-    return min(s for s, _ in wins), max(e for _, e in wins)
 
 
 def instruction(op_text: str) -> str:
@@ -92,31 +80,25 @@ def self_times(ops: List[Tuple[float, float, str]]) -> List[Tuple[str, float]]:
     return out
 
 
-def stage_split(data, export: Dict[str, Dict[str, str]]
+def stage_split(trace, export: Dict[str, Dict[str, str]]
                 ) -> Optional[StageSplit]:
-    """Per-stage device time of the heartbeats wholly inside the window,
-    or None where the trace holds no such ``jit_beat_*`` execution."""
-    window = window_ns(data)
-    planes = _device_planes(data)
-    if not planes:
+    """Per-stage device time of the heartbeats wholly inside the window
+    of ``trace`` (a ``trace_reduce.Trace``), or None where it holds no
+    such ``jit_beat_*`` execution."""
+    if trace.capture_ns is None:
         return None
-    lines = {ln.name: ln for ln in planes[0].lines}
-    if "XLA Modules" not in lines or _op_line(planes[0]) is None:
-        return None
-    ops = sorted((float(ev.start_ns), float(ev.start_ns + ev.duration_ns),
-                  instruction(ev.name)) for ev in _op_line(planes[0]).events)
-    if not ops:
-        return None
-    capture = (ops[0][0] + EDGE_NS, max(e for _, e, _ in ops) - EDGE_NS)
+    window = trace.window_ns
+    capture = (trace.capture_ns[0] + EDGE_NS, trace.capture_ns[1] - EDGE_NS)
     execs = []
-    for ev in lines["XLA Modules"].events:
-        s, e = float(ev.start_ns), float(ev.start_ns + ev.duration_ns)
-        module = ev.name.split("(", 1)[0]
+    for name, s, e in trace.modules:
+        module = name.split("(", 1)[0]
         if module.startswith(BEAT_MODULE) and window[0] <= s \
                 and e <= window[1] and capture[0] < s and e < capture[1]:
             execs.append((s, e, module))
     if not execs:
         return None
+    ops = sorted((ev.start_ns, ev.start_ns + ev.dur_ns, instruction(ev.name))
+                 for ev in trace.ops)
     scope_ns: Dict[str, float] = {}
     busy = mapped = 0.0
     i = 0
@@ -145,33 +127,14 @@ def stage_split(data, export: Dict[str, Dict[str, str]]
                       cover=100.0 * mapped / busy if busy > 0 else 0.0)
 
 
-def idle_by_program_span(data) -> Dict[str, float]:
-    """Seconds of the first device's idle time in the window under each
-    innermost ``beat.*`` host span, the rest under ``other``."""
-    window = window_ns(data)
-    planes = _device_planes(data)
-    line = _op_line(planes[0]) if planes else None
-    busy = []
-    for ev in (line.events if line is not None else ()):
-        c = clip(float(ev.start_ns), float(ev.start_ns + ev.duration_ns),
-                 window)
-        if c is not None:
-            busy.append(c)
-    busy = union_ns(busy)
-    spans = sorted((float(ev.start_ns), float(ev.start_ns + ev.duration_ns),
-                    ev.name)
-                   for p in data.planes if p.name.startswith("/host:")
-                   for ln in p.lines for ev in ln.events
-                   if ev.name.startswith(PROGRAM_SPAN))
-    gaps, t = [], window[0]
-    for s, e in busy:
-        if s > t:
-            gaps.append((t, s))
-        t = max(t, e)
-    if window[1] > t:
-        gaps.append((t, window[1]))
+def idle_by_program_span(trace) -> Dict[str, float]:
+    """Seconds of the first device's idle time in the window of ``trace``
+    under each innermost ``beat.*`` host span, the rest under ``other``."""
+    busy = union_ns([(ev.start_ns, ev.start_ns + ev.dur_ns)
+                     for ev in trace.ops])
+    spans = sorted((s, e, name) for name, s, e in trace.program_spans)
     out: Dict[str, float] = {}
-    for gs, ge in gaps:
+    for gs, ge in idle_ns(busy, trace.window_ns):
         over = [sp for sp in spans if sp[0] < ge and sp[1] > gs]
         cuts = sorted({gs, ge} | {x for sp in over for x in sp[:2]
                                   if gs < x < ge})
@@ -182,3 +145,13 @@ def idle_by_program_span(data) -> Dict[str, float]:
             name = max(inner)[2] if inner else "other"
             out[name] = out.get(name, 0.0) + (b - a) * 1e-9
     return out
+
+
+def stage_group(run, group: str) -> Optional[float]:
+    """One value of ``StageSplit.groups()`` for a run's view: its trace
+    split by the engine's op-to-stage map (``run.export``), or None where
+    the run was not traced or its trace holds no whole heartbeat."""
+    if run.trace is None or not run.export:
+        return None
+    split = stage_split(run.trace, run.export)
+    return None if split is None else split.groups()[group]

@@ -5,8 +5,10 @@
 
 Run from the root of a checkout of the repo, on a machine with a TPU.
 One process builds the cell's TPC-W deployment from ``--seed`` (the
-configuration file names its sizes), serves it with ``SharedDBEngine``
-(``kernels="auto"``, ``jit=True``, two beats in flight), warms every
+configuration file names its sizes and its row shards), serves it with
+``SharedDBEngine`` (``kernels="auto"``, ``jit=True``, two beats in flight,
+on a row mesh of the cell's chips where the file states ``"shards"`` > 1),
+warms every
 heartbeat flavour the traffic can reach (full, delta, delta-join), then
 lets the traffic file's emulated browsers drive ``dispatch()`` /
 ``collect()`` for ``--seconds``.  After the window it reads the tables
@@ -17,9 +19,13 @@ The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
 with ``--trace 1`` its per-layer ones), ``device``, with ``--trace 1``
 ``breakdown``, and last ``checks``: each number compared with its limit.
-The same comparisons end standard error.  Without a TPU, with fewer chips
-than the cell asks for, or outside a checkout that holds the program, it
-exits non-zero and prints no result.
+The same comparisons end standard error, after a ``run:`` record (peak
+memory of each device, and in a traced run each device's busy time and
+each heartbeat module's compiled memory) and, traced, a ``trace:`` record
+(the stage map's export time, stage times, idle time by program span).
+Without a TPU, with fewer chips than the cell asks for, with a cell whose
+chips differ from its configuration's shards, or outside a checkout that
+holds the program, it exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -84,6 +90,13 @@ class Cell:
         configs = {c["name"]: c for c in bench["configs"]}
         self.config = _load_json(os.path.join(
             root, configs[self.spec["config"]]["file"]))
+        # the row shards of core/sharding.py: one per chip, or none
+        shards = int(self.config.get("shards", 1))
+        if shards != self.chips:
+            raise BenchError(
+                f"workload {name!r} asks for {self.chips} chip(s), its "
+                f"configuration {self.spec['config']!r} states {shards} "
+                "row shard(s): they must agree")
         self.traffic = _load_json(os.path.join(
             root, "bench", "traffic", self.spec["traffic"] + ".json"))
 
@@ -170,7 +183,10 @@ class CompileStats:
 
 def build_engine(config: Dict, data):
     """The program's TPC-W plan and engine for this configuration, after
-    checking that the plan is the one the configuration file states."""
+    checking that the plan is the one the configuration file states.  A
+    configuration of ``"shards"`` > 1 runs on a row mesh of that many
+    devices; one of a single shard on one device, with no mesh (a
+    one-shard mesh answers alike but compiles other modules)."""
     from repro.core.executor import SharedDBEngine
     from repro.core.storage import UpdateSlots
     from repro.workloads import tpcw
@@ -182,8 +198,36 @@ def build_engine(config: Dict, data):
     s = config["update_slots"]
     slots = UpdateSlots(n_insert=s["insert"], n_update=s["update"],
                         n_delete=s["delete"])
+    shards, mesh = int(config.get("shards", 1)), None
+    if shards > 1:
+        from repro.core.sharding import make_row_mesh
+        mesh = make_row_mesh(shards)
     return SharedDBEngine(plan, slots, data, jit=True, kernels="auto",
-                          pipeline_depth=config["pipeline_depth"])
+                          pipeline_depth=config["pipeline_depth"], mesh=mesh)
+
+
+def beat_memory(eng) -> Dict[str, Dict[str, int]]:
+    """{module: bytes} of each heartbeat flavour the engine dispatched,
+    from the compiler's memory analysis: the flavours and argument shapes
+    that ``SharedDBEngine.beat_hlo`` compiles (hits in the compilation
+    cache).  Empty where the engine keeps no such record."""
+    cycles = {"full": getattr(eng, "_cycle", None),
+              "delta": getattr(eng, "_cycle_delta", None),
+              "delta_join": getattr(eng, "_cycle_delta_join", None)}
+    out = {}
+    for flavour, specs in getattr(eng, "_beat_args", {}).items():
+        fn = cycles.get(flavour)
+        if fn is None:
+            continue
+        m = fn.lower(*specs).compile().memory_analysis()
+        if m is None:
+            continue
+        out[f"jit_{fn.__name__}"] = {
+            "argument": int(m.argument_size_in_bytes),
+            "output": int(m.output_size_in_bytes),
+            "temp": int(m.temp_size_in_bytes),
+            "alias": int(m.alias_size_in_bytes)}
+    return out
 
 
 def check_plan(plan, config: Dict) -> None:
@@ -410,8 +454,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     c1 = cstats.snapshot()
     loop.close(GRACE_S)
     t_drained = time.perf_counter()
-    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in devices[:cell.chips])
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devices[:cell.chips]]
+    export = None
+    if trace:
+        t_export = time.perf_counter()
+        export = eng.trace_export()
+        export_s = time.perf_counter() - t_export
+        beat_mem = beat_memory(eng)
+        memory_s = time.perf_counter() - t_export - export_s
     read_back = {t: eng.snapshot(t) for t in cfg["tables"]}
     loop.engine = None
     del eng
@@ -450,7 +501,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     view = RunView(
         seconds=seconds, setup_s=setup_s, t_open=t_open, t_close=t_close,
         inters=window, beats=in_window, config=cfg, traffic=traffic,
-        trace=tr, device_kind=devices[0].device_kind)
+        trace=tr, export=export, device_kind=devices[0].device_kind)
     info = {
         "warm_up_paths": warm_paths,
         "compile": {"seconds": round(c0[0] - cs0[0], 3),
@@ -473,11 +524,28 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         "drain_s": round(t_drained - t_close, 3),
         "answers_checked": program.checked,
         "wrong_by_template": program.wrong_by,
-        "reference_s": round(ref_s, 3)}
+        "reference_s": round(ref_s, 3),
+        "peak_bytes_by_device": peaks}
     out = {"view": view, "checks": checks, "correct": correct,
            "attempted": len(window),
            "failed": sum(1 for r in window if r.done is None),
-           "peak": peak, "info": info}
+           "peak": max(peaks), "info": info}
+    if trace:
+        from program_trace import idle_by_program_span, stage_split
+        info["beat_memory"] = beat_mem
+        info["busy_s_by_device"] = tr.busy_s_by_device
+        split = stage_split(tr, export)
+        traced = [b.t_collected for b in in_window
+                  if t_trace <= b.t_collected < t_trace + trace_s]
+        out["trace_info"] = {
+            "export_s": round(export_s, 3),
+            "beat_memory_s": round(memory_s, 3),
+            "whole_beats": split.beats if split else 0,
+            "stage_ms": split.stage_ms if split else None,
+            # the beat on the host clock, for the stages to account for
+            "collect_gap_ms_median": float(np.median(np.diff(traced)))
+            * 1e3 if len(traced) > 1 else None,
+            "idle_by_program_span": idle_by_program_span(tr)}
     if stale is not None:
         # the control answers every ticket; its tables are its own
         c_checks = ctrl.checks(0, ref.tables, {
@@ -550,6 +618,8 @@ def main(argv: Optional[List[str]] = None) -> int:
                              "idle_gaps": tr.idle_gaps}
     line["checks"] = res["checks"]
     log("run: " + json.dumps(res["info"]))
+    if "trace_info" in res:
+        log("trace: " + json.dumps(res["trace_info"]))
     for name, c in res["checks"].items():
         log(f"check {name}: {c['value']} (limit {c['limit']})")
     print(json.dumps(line), flush=True)

@@ -13,7 +13,10 @@ clipped to it.  All times are on the profiler's common clock.
 * idle gaps: the window less the busy union on the first device, each
   gap's time split among the harness's host spans (``generate``,
   ``dispatch``, ``collect``, ``wait``) that overlap it, the rest
-  ``other``.
+  ``other``;
+* for ``program_trace``, which reads them with the program's own names:
+  the first device's ``XLA Modules`` events and the extent of its op
+  line (the profiler's capture), and the engine's ``beat.*`` host spans.
 """
 from __future__ import annotations
 
@@ -25,6 +28,7 @@ from typing import Dict, List, Optional, Tuple
 
 HOST_SPANS = ("generate", "dispatch", "collect", "wait")
 WINDOW_SPAN = "trace_window"
+PROGRAM_SPAN = "beat."
 _INSTANCE = re.compile(r"\.\d+$")
 _SHAPE = re.compile(r"\b([a-z]+\d*|pred)\[([\d,]*)\]")
 
@@ -46,6 +50,15 @@ class Trace:
     host: List[Tuple[str, float, float]]     # (span, start_ns, end_ns)
     device_ops: List[List]                   # [[name, seconds]], top 10
     idle_gaps: List[List]                    # [[host activity, seconds]]
+    busy_s_by_device: List[float] = dataclasses.field(default_factory=list)
+    # first device, not clipped: (module, start_ns, end_ns) of each
+    # ``XLA Modules`` event, and its op line's first start and last end
+    modules: List[Tuple[str, float, float]] = dataclasses.field(
+        default_factory=list)
+    capture_ns: Optional[Tuple[float, float]] = None
+    # the engine's ``beat.*`` host spans: (span, start_ns, end_ns)
+    program_spans: List[Tuple[str, float, float]] = dataclasses.field(
+        default_factory=list)
 
 
 def op_name(name: str) -> str:
@@ -80,10 +93,9 @@ def clip(s: float, e: float, w: Tuple[float, float]) -> Optional[Tuple[float, fl
     return (s, e) if e > s else None
 
 
-def gaps_by_activity(busy: List[Tuple[float, float]],
-                     window: Tuple[float, float],
-                     host: List[Tuple[str, float, float]]) -> Dict[str, float]:
-    """Seconds of device idle time spent under each host activity."""
+def idle_ns(busy: List[Tuple[float, float]],
+            window: Tuple[float, float]) -> List[Tuple[float, float]]:
+    """The window less a sorted busy union: its idle intervals."""
     gaps, t = [], window[0]
     for s, e in busy:
         if s > t:
@@ -91,6 +103,14 @@ def gaps_by_activity(busy: List[Tuple[float, float]],
         t = max(t, e)
     if window[1] > t:
         gaps.append((t, window[1]))
+    return gaps
+
+
+def gaps_by_activity(busy: List[Tuple[float, float]],
+                     window: Tuple[float, float],
+                     host: List[Tuple[str, float, float]]) -> Dict[str, float]:
+    """Seconds of device idle time spent under each host activity."""
+    gaps = idle_ns(busy, window)
     spans = sorted((s, e, n) for n, s, e in host if n in HOST_SPANS)
     out: Dict[str, float] = {}
     for gs, ge in gaps:
@@ -120,15 +140,18 @@ def _op_line(plane):
 
 def reduce_xspace(data) -> Trace:
     """The reduction of one loaded ``jax.profiler.ProfileData``."""
-    host = []
+    host, program = [], []
     for p in data.planes:
         if not p.name.startswith("/host:"):
             continue
         for ln in p.lines:
             for ev in ln.events:
+                span = (ev.name, float(ev.start_ns),
+                        float(ev.start_ns + ev.duration_ns))
                 if ev.name in HOST_SPANS or ev.name == WINDOW_SPAN:
-                    host.append((ev.name, float(ev.start_ns),
-                                 float(ev.start_ns + ev.duration_ns)))
+                    host.append(span)
+                elif ev.name.startswith(PROGRAM_SPAN):
+                    program.append(span)
     wins = [(s, e) for n, s, e in host if n == WINDOW_SPAN]
     if not wins:
         raise ValueError(f"trace has no {WINDOW_SPAN!r} host span")
@@ -136,15 +159,22 @@ def reduce_xspace(data) -> Trace:
     planes = _device_planes(data)
     if not planes:
         raise ValueError("trace has no device plane with events")
-    busy_per_dev, first_busy, ops = [], None, []
+    busy_per_dev, first_busy, ops, capture = [], None, [], None
+    lines = {ln.name: ln for ln in planes[0].lines}
+    modules = [(ev.name, float(ev.start_ns),
+                float(ev.start_ns + ev.duration_ns))
+               for ev in (lines["XLA Modules"].events
+                          if "XLA Modules" in lines else ())]
     for i, p in enumerate(planes):
         line = _op_line(p)
         if line is None:
             continue
-        iv = []
+        iv, unclipped = [], []
         for ev in line.events:
-            c = clip(float(ev.start_ns), float(ev.start_ns + ev.duration_ns),
-                     window)
+            s, e = float(ev.start_ns), float(ev.start_ns + ev.duration_ns)
+            if i == 0:
+                unclipped.append((s, e))
+            c = clip(s, e, window)
             if c is None:
                 continue
             iv.append(c)
@@ -155,6 +185,9 @@ def reduce_xspace(data) -> Trace:
         busy_per_dev.append(sum(e - s for s, e in merged) * 1e-9)
         if i == 0:
             first_busy = merged
+            if unclipped:
+                capture = (min(s for s, _ in unclipped),
+                           max(e for _, e in unclipped))
     fam: Dict[str, float] = {}
     for ev in ops:
         fam[op_name(ev.name)] = fam.get(op_name(ev.name), 0.0) \
@@ -167,7 +200,9 @@ def reduce_xspace(data) -> Trace:
         device_ops=[[n, s] for n, s in sorted(fam.items(),
                                                key=lambda kv: -kv[1])[:10]],
         idle_gaps=[[n, s] for n, s in sorted(idle.items(),
-                                              key=lambda kv: -kv[1])[:10]])
+                                              key=lambda kv: -kv[1])[:10]],
+        busy_s_by_device=busy_per_dev, modules=modules, capture_ns=capture,
+        program_spans=program)
 
 
 def reduce_dir(trace_dir: str) -> Trace:
